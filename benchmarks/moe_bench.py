@@ -3,10 +3,10 @@ vs mesh-sharded expert parallelism, over a T x experts grid.
 
 Rows land in the machine-readable ``BENCH_moe.json`` trajectory (written
 by ``benchmarks/run.py``), so dispatch-path regressions show up PR over
-PR.  The single-device impls run in-process; the ``sharded`` rows run in
-a subprocess with a forced 4-device CPU topology (the repo convention —
-jax pins the device count at first init).  CPU wall time: the trajectory
-tracks *relative* dispatch cost, TPU performance is the roofline's job.
+PR.  Everything runs in this process when it has 4 devices; on a CPU
+host with one, the ``sharded`` rows run in a child with a forced 4-device
+CPU topology (jax pins the device count at first init).  CPU wall time:
+the trajectory tracks *relative* dispatch cost, not chip performance.
 """
 from __future__ import annotations
 
@@ -31,62 +31,79 @@ GRAD_SHAPE = (512, 8)                    # (T, E) for the train-grad rows
 GRAD_IMPLS = ["gather", "dense", "reference", "pallas"]
 N_SHARDS = 4
 
-_SHARDED_CODE = """
-import functools, json, sys
-import numpy as np, jax, jax.numpy as jnp
-sys.path.insert(0, {bench_dir!r})
-from timing import time_us
-from repro.models.common import init_params
-from repro.models.config import MoEConfig
-from repro.models.moe import moe_defs, moe_forward_sharded, expert_capacity
 
-for T, E in {shapes}:
-    moe = MoEConfig(n_experts=E, top_k={top_k},
-                    capacity_factor={capacity_factor})
-    params = init_params(moe_defs({d}, {d_ff}, moe, "swiglu"),
-                         jax.random.key(0), jnp.float32)
-    B = {n_shards} * 2
-    x = jax.random.normal(jax.random.key(1), (B, T // B, {d}))
-    mesh = jax.make_mesh(({n_shards},), ("expert",))
-    cap = expert_capacity(T, moe)
-    fn = jax.jit(lambda p, xx: moe_forward_sharded(
-        p, xx, moe, "swiglu", mesh=mesh, capacity=cap))
-    us = time_us(fn, params, x)
-    y, stats = fn(params, x)
-    print(json.dumps({{
-        "impl": "sharded", "T": T, "E": E, "d": {d},
-        "forward_us": round(us, 1),
-        "tokens_per_s": round(T / (us * 1e-6)),
-        "dropped": int(stats["dropped"]),
-        "remote_packets": int(stats["remote_packets"]),
-        "local_packets": int(stats["local_packets"]),
-    }}))
-print("MOE_BENCH_SHARDED_DONE")
-"""
+def _sharded_rows_here() -> List[dict]:
+    """Time ``moe_forward_sharded`` over this process's first
+    ``N_SHARDS`` devices."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.launch.mesh import make_mesh
+    from repro.models.common import init_params
+    from repro.models.config import MoEConfig
+    from repro.models.moe import (expert_capacity, moe_defs,
+                                  moe_forward_sharded)
+
+    rows = []
+    for T, E in SHAPES:
+        moe = MoEConfig(n_experts=E, top_k=TOP_K,
+                        capacity_factor=CAPACITY_FACTOR)
+        params = init_params(moe_defs(D, D_FF, moe, "swiglu"),
+                             jax.random.key(0), jnp.float32)
+        B = N_SHARDS * 2
+        x = jax.random.normal(jax.random.key(1), (B, T // B, D))
+        mesh = make_mesh((N_SHARDS,), ("expert",))
+        cap = expert_capacity(T, moe)
+        fn = jax.jit(lambda p, xx, moe=moe, mesh=mesh, cap=cap:
+                     moe_forward_sharded(p, xx, moe, "swiglu", mesh=mesh,
+                                         capacity=cap))
+        us = time_us(fn, params, x)
+        y, stats = fn(params, x)
+        rows.append({
+            "impl": "sharded", "T": T, "E": E, "d": D,
+            "forward_us": round(us, 1),
+            "tokens_per_s": round(T / (us * 1e-6)),
+            "dropped": int(stats["dropped"]),
+            "remote_packets": int(stats["remote_packets"]),
+            "local_packets": int(stats["local_packets"]),
+        })
+    return rows
 
 
 def _sharded_rows() -> Tuple[List[dict], str]:
-    """Run the sharded impl on a forced multi-device topology."""
-    code = _SHARDED_CODE.format(shapes=SHAPES, top_k=TOP_K,
-                                capacity_factor=CAPACITY_FACTOR, d=D,
-                                d_ff=D_FF, n_shards=N_SHARDS,
-                                bench_dir=str(
-                                    Path(__file__).resolve().parent))
+    """The sharded impl's rows, one process per chip.
+
+    With ``N_SHARDS`` devices in this process the rows are timed here.
+    Under ``JAX_PLATFORMS=cpu`` with fewer, a child process with a forced
+    ``N_SHARDS``-device host topology times them (jax pins the device
+    count at first init); a child that fails raises.  On an accelerator
+    with fewer devices there is no sharded row: the parent holds the
+    chip, so no child could reach it."""
+    import jax
+
+    if jax.device_count() >= N_SHARDS:
+        return _sharded_rows_here(), (
+            f"{N_SHARDS} of {jax.device_count()} "
+            f"{jax.devices()[0].platform} devices (in process)")
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return [], (f"not measured: needs {N_SHARDS} devices, "
+                    f"{jax.device_count()} present")
+    repo = Path(__file__).resolve().parent.parent
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count"
                         f"={N_SHARDS}")
-    src = Path(__file__).resolve().parent.parent / "src"
-    env["PYTHONPATH"] = str(src) + os.pathsep + env.get("PYTHONPATH", "")
-    try:
-        res = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=600)
-    except subprocess.TimeoutExpired:
-        return [], "sharded: subprocess timed out"
-    if res.returncode != 0 or "MOE_BENCH_SHARDED_DONE" not in res.stdout:
-        return [], f"sharded: subprocess failed: {res.stderr[-400:]}"
-    rows = [json.loads(line) for line in res.stdout.splitlines()
-            if line.startswith("{")]
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(repo / "src"), str(repo), env.get("PYTHONPATH", "")])
+    code = ("import json\n"
+            "from benchmarks.moe_bench import _sharded_rows_here\n"
+            "print(json.dumps(_sharded_rows_here()))\n")
+    res = subprocess.run([sys.executable, "-c", code], env=env, cwd=repo,
+                         capture_output=True, text=True, timeout=600)
+    if res.returncode != 0:
+        raise RuntimeError(f"sharded MoE bench child failed "
+                           f"(rc={res.returncode}): {res.stderr[-2000:]}")
+    rows = json.loads(res.stdout.strip().splitlines()[-1])
     return rows, f"forced {N_SHARDS}-device CPU topology (subprocess)"
 
 

@@ -57,6 +57,8 @@ TRAJECTORY_FILES = {"fabric": "BENCH_fabric.json",
 
 
 def main(argv=None) -> int:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args = list(argv if argv is not None else sys.argv[1:])
     predictive = "--predictive" in args
     if predictive:

@@ -196,4 +196,6 @@ if __name__ == "__main__":
                     help="run the cached-decode fast-path demo instead of "
                          "the full lifecycle script")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     steady_state() if args.steady_state else main()

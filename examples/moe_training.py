@@ -68,6 +68,7 @@ def sharded_demo(n_devices: int) -> None:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.launch.mesh import make_mesh
     from repro.models.common import init_params
     from repro.models.moe import (moe_defs, moe_fabric, moe_forward_sharded)
     from repro.shell import FailRegion, Grow, Shell
@@ -79,7 +80,7 @@ def sharded_demo(n_devices: int) -> None:
     params = init_params(moe_defs(d, 128, moe, "swiglu"),
                          jax.random.key(0), jnp.float32)
     x = jax.random.normal(jax.random.key(1), (n_devices * 2, 32, d))
-    mesh = jax.make_mesh((n_devices,), ("expert",))
+    mesh = make_mesh((n_devices,), ("expert",))
     CAP = 256
 
     # Control plane: E crossbar ports = host + (E-1) regions; the MoE's
@@ -187,4 +188,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

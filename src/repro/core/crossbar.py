@@ -37,14 +37,6 @@ def _warn_deprecated(what: str, use: str) -> None:
                   DeprecationWarning, stacklevel=3)
 
 
-def _axis_size(axis_name: str) -> int:
-    # jax<0.5 has no jax.lax.axis_size; psum of ones is the portable spelling.
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 # ----------------------------------------------------------------------
 # Local (single-shard) crossbar — shim over the fabric reference backend.
 # ----------------------------------------------------------------------
@@ -120,7 +112,7 @@ def exchange_sharded(x: jax.Array, dst: jax.Array, regs: CrossbarRegisters,
     _warn_deprecated("core.crossbar.exchange_sharded",
                      'Fabric(regs, backend="sharded", axis_name=...)'
                      ".dispatch inside shard_map (oracle-identical slots)")
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     keep, slot, _err = pairwise_dispatch_plan(dst, me, regs, capacity)
 
@@ -145,7 +137,7 @@ def combine_sharded(y: jax.Array, dst: jax.Array, keep: jax.Array,
     _warn_deprecated("core.crossbar.combine_sharded",
                      'Fabric(regs, backend="sharded", axis_name=...)'
                      ".combine inside shard_map")
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     back = jax.lax.all_to_all(y, axis_name, split_axis=0, concat_axis=0,
                               tiled=False)                     # [n, cap, D]
     dst_oh = jax.nn.one_hot(dst, n, dtype=y.dtype)

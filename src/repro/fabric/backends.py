@@ -251,13 +251,6 @@ class CombineRoute:
     dshard: jax.Array      # [T] int32 — destination shard per packet
 
 
-def _axis_size(axis_name: str) -> int:
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 # ----------------------------------------------------------------------
 # sharded data movement with custom VJPs
 #
@@ -450,7 +443,7 @@ class ShardedBackend:
 
     def ports_per_shard(self, regs: CrossbarRegisters) -> int:
         """Slave ports each shard owns; ``n_ports`` must divide evenly."""
-        n_src = _axis_size(self.axis_name)
+        n_src = jax.lax.axis_size(self.axis_name)
         n_dst = regs.n_ports
         if n_dst % n_src:
             raise ValueError(
@@ -504,7 +497,7 @@ class ShardedBackend:
         slot`` address (no [T, n_dst, C] selection tensor); slots are
         globally unique per destination, so the per-source contributions
         coming out of the ``all_to_all`` just sum."""
-        n_src = _axis_size(self.axis_name)
+        n_src = jax.lax.axis_size(self.axis_name)
         n_dst = regs.n_ports
         pps = self.ports_per_shard(regs)
         addr = arbiter.flat_slot_addr(plan, n_dst, capacity)
@@ -522,7 +515,7 @@ class ShardedBackend:
         via ``combine(..., route=...)`` (a shell event that bumps the epoch
         changes the plan, so the route must be rebuilt with it)."""
         ax = self.axis_name
-        n_src = _axis_size(ax)
+        n_src = jax.lax.axis_size(ax)
         n_dst = plan.counts.shape[0]
         pps = n_dst // n_src
         C = capacity
@@ -573,7 +566,7 @@ class ShardedBackend:
         setup is paid once per reconfiguration, not per token.  Results
         are bit-identical with and without a route."""
         ax = self.axis_name
-        n_src = _axis_size(ax)
+        n_src = jax.lax.axis_size(ax)
         pps, C, D = y.shape
         T = plan.dst.shape[0]
         if T == 0 or C == 0:        # nothing sent / nothing grantable

@@ -111,7 +111,7 @@ class Fabric:
         Calls already inside a trace keep their checks only when ``debug``
         was passed *explicitly* — the caller must then functionalize them
         (``checkify.checkify`` around its outer jit; ``shard_map`` bodies
-        additionally need ``check_rep=False``).  Env-sourced debug skips
+        additionally need ``check_vma=False``).  Env-sourced debug skips
         in-trace checks so exporting the variable cannot break programs
         that never opted in.
     plan_cache:

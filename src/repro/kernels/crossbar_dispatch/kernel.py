@@ -9,16 +9,31 @@ dispatch of the WB crossbar for one source region (the ``pairwise`` plan of
    (the arbiter's package counters); isolation (one-hot AND), quota and
    capacity checks are VPU compares against register-file rows.
 2. ``scatter``  — packs granted packets into per-destination slabs
-   [S, C, D]. Grid (destination, token-block); each cell builds a
-   (block_t x C) slot-selection one-hot and accumulates ``sel^T @ x`` on the
+   [S, C, D]. Grid (destination, D tile, token-block); each cell builds a
+   (C x block_t) slot-selection one-hot and accumulates ``sel @ x`` on the
    MXU — dynamic scatter re-expressed as a matmul, which is the TPU-native
    way to move rows (no per-row DMA).
-3. ``combine``  — the inverse gather: ``sel @ slab`` accumulated over
+3. ``combine``  — the inverse gather: ``sel^T @ slab`` accumulated over
    destinations brings expert/module outputs back to packet order, applying
    combine weights.
 
-VMEM budget per cell at (block_t=256, C<=512, D=128..512): x tile
-(256 x D x 4 B) + slab tile (C x D x 4 B) + one-hots — well under 4 MB.
+Layout (what Mosaic accepts). Per-packet vectors (dst, src, keep, slot)
+travel as ``[nb, 1, block_t]`` arrays: each grid step reads one
+``(1, block_t)`` row whose block equals the array's last two dims, so the
+(8, 128) block-tiling rule holds at every offer size and no 1-D ref is ever
+read (1-D reads lower to shape casts the compiler refuses).  Packets run
+along lanes: one-hots are ``[ports, block_t]`` (or ``[C, block_t]``), per-
+packet reductions go over sublanes, per-port ones over lanes.  Register
+vectors are ``[ports, 1]`` columns, and so are the combine weights
+(``[nb, block_t, 1]``), which scale gathered ``[block_t, D]`` rows.  The
+in-block exclusive prefix count of the plan sweep is a matmul with a
+strictly-upper-triangular 0/1 matrix on the MXU (0/1 operands and f32
+accumulation are exact up to 2^24), since Mosaic has no cumsum.
+
+VMEM per cell: the one-hots and the (block_t x block_t) triangle are well
+under 1 MB at block_t = 256; scatter/combine tile D (``_d_tile``, at most
+512 lanes) so the slab tile ``C x 512`` stays a few MB even at prefill
+capacities (C ~ 1280, D = 4096).
 All three kernels are exact against ``ref.py`` (same grant order, same error
 codes), which in turn matches the cycle-level hardware arbiter at package
 granularity.
@@ -32,9 +47,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.core.registers import ErrorCode
+
+
+def _rows(v: jax.Array, block_t: int) -> jax.Array:
+    """[T] per-packet vector -> [nb, 1, block_t] kernel row layout."""
+    return v.reshape(-1, 1, block_t)
+
+
+def _row_spec(block_t: int, index_map) -> pl.BlockSpec:
+    """One (1, block_t) packet row per grid step (leading dim squeezed)."""
+    return pl.BlockSpec((pl.squeezed, 1, block_t), index_map)
+
+
+def _excl_prefix(live: jax.Array) -> jax.Array:
+    """Exclusive prefix count along lanes of a 0/1 ``[rows, bT]`` int32
+    matrix: ``live @ U`` with ``U[j, i] = 1`` iff ``j < i`` (MXU)."""
+    bT = live.shape[1]
+    upper = (jax.lax.broadcasted_iota(jnp.int32, (bT, bT), 0)
+             < jax.lax.broadcasted_iota(jnp.int32, (bT, bT), 1))
+    return jnp.dot(live.astype(jnp.float32), upper.astype(jnp.float32),
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _exact(dtype):
+    """Matmul precision under which a one-hot operand copies rows exactly:
+    HIGHEST keeps f32 rows from being rounded to bf16 on the MXU; bf16
+    rows are exact at the default (and Mosaic takes HIGHEST on f32 only)."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def _d_tile(D: int) -> int:
+    """Lane tile of the feature dim for scatter/combine (bounds VMEM)."""
+    for td in (512, 256, 128):
+        if D % td == 0:
+            return td
+    return D
 
 
 # ======================================================================
@@ -48,23 +96,20 @@ def _plan_kernel(dst_ref, allowed_ref, quota_ref, cap_ref,
     @pl.when(tb == 0)
     def _init():
         count_scratch[...] = jnp.zeros_like(count_scratch)
+        counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    dst = dst_ref[0]                                          # [bT] int32
-    allowed = allowed_ref[0]                                  # [S] int32 (0/1)
-    quota = quota_ref[0]                                      # [S] int32
-    cap = cap_ref[0]                                          # [S] int32
+    dst = dst_ref[...]                                        # [1, bT]
+    dst_oh = (jax.lax.broadcasted_iota(jnp.int32, (n_ports, block_t), 0)
+              == dst).astype(jnp.int32)                       # [S, bT]
+    iso_ok = jnp.sum(dst_oh * allowed_ref[...], axis=0,
+                     keepdims=True) > 0                       # [1, bT]
 
-    dst_oh = (dst[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block_t, n_ports), 1)).astype(jnp.int32)  # [bT, S]
-    iso_ok = jnp.sum(dst_oh * allowed[None, :], axis=1) > 0   # [bT] bool
+    live = dst_oh * iso_ok.astype(jnp.int32)
+    rank = jnp.sum(dst_oh * (_excl_prefix(live) + count_scratch[...]),
+                   axis=0, keepdims=True)                     # [1, bT]
 
-    live = dst_oh * iso_ok[:, None].astype(jnp.int32)
-    ex_cum = jnp.cumsum(live, axis=0) - live                  # [bT, S]
-    rank = (jnp.sum(dst_oh * ex_cum, axis=1)
-            + jnp.sum(dst_oh * count_scratch[0][None, :], axis=1))
-
-    quota_t = jnp.sum(dst_oh * quota[None, :], axis=1)
-    cap_t = jnp.sum(dst_oh * cap[None, :], axis=1)
+    quota_t = jnp.sum(dst_oh * quota_ref[...], axis=0, keepdims=True)
+    cap_t = jnp.sum(dst_oh * cap_ref[...], axis=0, keepdims=True)
     quota_ok = (quota_t == 0) | (rank < quota_t)
     cap_ok = rank < cap_t
     keep = iso_ok & quota_ok & cap_ok
@@ -74,15 +119,13 @@ def _plan_kernel(dst_ref, allowed_ref, quota_ref, cap_ref,
             jnp.where(~cap_ok, jnp.int32(ErrorCode.ACK_TIMEOUT),
                       jnp.int32(ErrorCode.OK))))
 
-    keep_ref[0] = keep.astype(jnp.int32)
-    slot_ref[0] = jnp.where(keep, rank, 0).astype(jnp.int32)
-    err_ref[0] = err
+    keep_ref[...] = keep.astype(jnp.int32)
+    slot_ref[...] = jnp.where(keep, rank, 0)
+    err_ref[...] = err
 
-    count_scratch[...] = count_scratch[...] + jnp.sum(live, axis=0)[None, :]
-    granted = dst_oh * keep[:, None].astype(jnp.int32)
-    counts_ref[...] = jnp.where(
-        tb == 0, jnp.sum(granted, axis=0)[None, :],
-        counts_ref[...] + jnp.sum(granted, axis=0)[None, :])
+    count_scratch[...] += jnp.sum(live, axis=1, keepdims=True)
+    counts_ref[...] += jnp.sum(dst_oh * keep.astype(jnp.int32), axis=1,
+                               keepdims=True)
 
 
 @functools.partial(jax.jit,
@@ -99,34 +142,23 @@ def plan_call(dst: jax.Array, allowed_row: jax.Array, quota_row: jax.Array,
     T = dst.shape[0]
     nb = T // block_t
     kernel = functools.partial(_plan_kernel, n_ports=n_ports, block_t=block_t)
+    row = _row_spec(block_t, lambda i: (i, 0, 0))
+    col = pl.BlockSpec((n_ports, 1), lambda i: (0, 0))
+    out_row = jax.ShapeDtypeStruct((nb, 1, block_t), jnp.int32)
     keep, slot, err, counts = pl.pallas_call(
         kernel,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, block_t), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_ports), lambda i: (0, 0)),
-            pl.BlockSpec((1, n_ports), lambda i: (0, 0)),
-            pl.BlockSpec((1, n_ports), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_t), lambda i: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda i: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_ports), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, block_t), jnp.int32),
-            jax.ShapeDtypeStruct((nb, block_t), jnp.int32),
-            jax.ShapeDtypeStruct((nb, block_t), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_ports), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, n_ports), jnp.int32)],
-        compiler_params=CompilerParams(
+        in_specs=[row, col, col, col],
+        out_specs=[row, row, row, col],
+        out_shape=[out_row, out_row, out_row,
+                   jax.ShapeDtypeStruct((n_ports, 1), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((n_ports, 1), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(dst.reshape(nb, block_t), allowed_row.reshape(1, -1),
-      quota_row.reshape(1, -1), capacity.reshape(1, -1))
-    return keep.reshape(T), slot.reshape(T), err.reshape(T), counts[0]
+    )(_rows(dst, block_t), allowed_row.reshape(-1, 1),
+      quota_row.reshape(-1, 1), capacity.reshape(-1, 1))
+    return keep.reshape(T), slot.reshape(T), err.reshape(T), counts[:, 0]
 
 
 # ======================================================================
@@ -140,7 +172,7 @@ def _plan_multi_kernel(dst_ref, src_ref, allowed_ref, quota_ref,
     One grid pass over token blocks computes, for *every* (src, dst)
     stream at once, the per-packet stream ranks and iso/quota verdicts —
     replacing the n_ports separate ``plan`` launches (and their stacked
-    [n, T] intermediates) the backend used to sweep.  The [1, n^2] VMEM
+    [n, T] intermediates) the backend used to sweep.  The [n^2, 1] VMEM
     scratch carries the per-pair live counts between blocks (the
     arbiter's package counters, one per stream); the flattened register
     matrices index by ``pair = src * n + dst``.  Capacity is *not*
@@ -152,28 +184,26 @@ def _plan_multi_kernel(dst_ref, src_ref, allowed_ref, quota_ref,
     @pl.when(tb == 0)
     def _init():
         live_scratch[...] = jnp.zeros_like(live_scratch)
+        granted_ref[...] = jnp.zeros_like(granted_ref)
 
     n2 = n_ports * n_ports
-    dst = dst_ref[0]                                          # [bT] int32
-    src = src_ref[0]                                          # [bT] int32
-    allowed = allowed_ref[0]                                  # [n2] 0/1
-    quota = quota_ref[0]                                      # [n2] int32
+    dst = dst_ref[...]                                        # [1, bT]
+    src = src_ref[...]                                        # [1, bT]
 
     valid = ((dst >= 0) & (dst < n_ports)
-             & (src >= 0) & (src < n_ports))                  # [bT]
+             & (src >= 0) & (src < n_ports))                  # [1, bT]
     pair = (jnp.clip(src, 0, n_ports - 1) * n_ports
             + jnp.clip(dst, 0, n_ports - 1))
-    pair_oh = ((pair[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block_t, n2), 1))
-        & valid[:, None]).astype(jnp.int32)                   # [bT, n2]
-    iso_ok = jnp.sum(pair_oh * allowed[None, :], axis=1) > 0  # [bT]
+    pair_oh = ((jax.lax.broadcasted_iota(jnp.int32, (n2, block_t), 0)
+                == pair) & valid).astype(jnp.int32)           # [n2, bT]
+    iso_ok = jnp.sum(pair_oh * allowed_ref[...], axis=0,
+                     keepdims=True) > 0                       # [1, bT]
 
-    live = pair_oh * iso_ok[:, None].astype(jnp.int32)
-    ex_cum = jnp.cumsum(live, axis=0) - live                  # [bT, n2]
-    rank = (jnp.sum(pair_oh * ex_cum, axis=1)
-            + jnp.sum(pair_oh * live_scratch[0][None, :], axis=1))
+    live = pair_oh * iso_ok.astype(jnp.int32)
+    rank = jnp.sum(pair_oh * (_excl_prefix(live) + live_scratch[...]),
+                   axis=0, keepdims=True)                     # [1, bT]
 
-    quota_t = jnp.sum(pair_oh * quota[None, :], axis=1)
+    quota_t = jnp.sum(pair_oh * quota_ref[...], axis=0, keepdims=True)
     quota_ok = (quota_t == 0) | (rank < quota_t)
     keep = iso_ok & quota_ok
 
@@ -181,14 +211,13 @@ def _plan_multi_kernel(dst_ref, src_ref, allowed_ref, quota_ref,
            jnp.where(~quota_ok, jnp.int32(ErrorCode.GRANT_TIMEOUT),
                      jnp.int32(ErrorCode.OK)))
 
-    keep_ref[0] = keep.astype(jnp.int32)
-    rank_ref[0] = jnp.where(iso_ok, rank, 0)
-    err_ref[0] = err
+    keep_ref[...] = keep.astype(jnp.int32)
+    rank_ref[...] = jnp.where(iso_ok, rank, 0)
+    err_ref[...] = err
 
-    live_scratch[...] = live_scratch[...] + jnp.sum(live, axis=0)[None, :]
-    granted = jnp.sum(pair_oh * keep[:, None].astype(jnp.int32), axis=0)
-    granted_ref[...] = jnp.where(
-        tb == 0, granted[None, :], granted_ref[...] + granted[None, :])
+    live_scratch[...] += jnp.sum(live, axis=1, keepdims=True)
+    granted_ref[...] += jnp.sum(pair_oh * keep.astype(jnp.int32), axis=1,
+                                keepdims=True)
 
 
 @functools.partial(jax.jit,
@@ -209,33 +238,22 @@ def plan_multi_call(dst: jax.Array, src: jax.Array, allowed_sd: jax.Array,
     n2 = n_ports * n_ports
     kernel = functools.partial(_plan_multi_kernel, n_ports=n_ports,
                                block_t=block_t)
+    row = _row_spec(block_t, lambda i: (i, 0, 0))
+    col = pl.BlockSpec((n2, 1), lambda i: (0, 0))
+    out_row = jax.ShapeDtypeStruct((nb, 1, block_t), jnp.int32)
     keep, rank, err, granted = pl.pallas_call(
         kernel,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, block_t), lambda i: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda i: (i, 0)),
-            pl.BlockSpec((1, n2), lambda i: (0, 0)),
-            pl.BlockSpec((1, n2), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_t), lambda i: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda i: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda i: (i, 0)),
-            pl.BlockSpec((1, n2), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, block_t), jnp.int32),
-            jax.ShapeDtypeStruct((nb, block_t), jnp.int32),
-            jax.ShapeDtypeStruct((nb, block_t), jnp.int32),
-            jax.ShapeDtypeStruct((1, n2), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, n2), jnp.int32)],
-        compiler_params=CompilerParams(
+        in_specs=[row, row, col, col],
+        out_specs=[row, row, row, col],
+        out_shape=[out_row, out_row, out_row,
+                   jax.ShapeDtypeStruct((n2, 1), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((n2, 1), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(dst.reshape(nb, block_t), src.reshape(nb, block_t),
-      allowed_sd.reshape(1, n2), quota_sd.reshape(1, n2))
+    )(_rows(dst, block_t), _rows(src, block_t),
+      allowed_sd.reshape(n2, 1), quota_sd.reshape(n2, 1))
     return (keep.reshape(T), rank.reshape(T), err.reshape(T),
             granted.reshape(n_ports, n_ports))
 
@@ -246,20 +264,18 @@ def plan_multi_call(dst: jax.Array, src: jax.Array, allowed_sd: jax.Array,
 def _scatter_kernel(x_ref, dst_ref, keep_ref, slot_ref, slab_ref, *,
                     capacity: int, block_t: int):
     s = pl.program_id(0)
-    tb = pl.program_id(1)
+    tb = pl.program_id(2)
 
     @pl.when(tb == 0)
     def _init():
         slab_ref[...] = jnp.zeros_like(slab_ref)
 
-    x = x_ref[...]                                            # [bT, D]
-    mine = ((dst_ref[0] == s) & (keep_ref[0] > 0))            # [bT]
-    slot = slot_ref[0]                                        # [bT]
-    sel = ((slot[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block_t, capacity), 1))
-        & mine[:, None]).astype(x.dtype)                      # [bT, C]
-    slab_ref[0] += jax.lax.dot_general(
-        sel, x, (((0,), (0,)), ((), ())),
+    x = x_ref[...]                                            # [bT, tD]
+    mine = (dst_ref[...] == s) & (keep_ref[...] > 0)          # [1, bT]
+    sel = ((jax.lax.broadcasted_iota(jnp.int32, (capacity, block_t), 0)
+            == slot_ref[...]) & mine).astype(x.dtype)         # [C, bT]
+    slab_ref[...] += jnp.dot(
+        sel, x, precision=_exact(x.dtype),
         preferred_element_type=jnp.float32).astype(slab_ref.dtype)
 
 
@@ -272,24 +288,22 @@ def scatter_call(x: jax.Array, dst: jax.Array, keep: jax.Array,
     """x: [T, D] -> slabs [n_ports, capacity, D]."""
     T, D = x.shape
     nb = T // block_t
+    td = _d_tile(D)
     kernel = functools.partial(_scatter_kernel, capacity=capacity,
                                block_t=block_t)
+    row = _row_spec(block_t, lambda s, j, i: (i, 0, 0))
     return pl.pallas_call(
         kernel,
-        grid=(n_ports, nb),
-        in_specs=[
-            pl.BlockSpec((block_t, D), lambda s, i: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda s, i: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda s, i: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda s, i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, capacity, D), lambda s, i: (s, 0, 0)),
+        grid=(n_ports, D // td, nb),
+        in_specs=[pl.BlockSpec((block_t, td), lambda s, j, i: (i, j)),
+                  row, row, row],
+        out_specs=pl.BlockSpec((pl.squeezed, capacity, td),
+                               lambda s, j, i: (s, 0, j)),
         out_shape=jax.ShapeDtypeStruct((n_ports, capacity, D), x.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dst.reshape(nb, block_t), keep.reshape(nb, block_t),
-      slot.reshape(nb, block_t))
+    )(x, _rows(dst, block_t), _rows(keep, block_t), _rows(slot, block_t))
 
 
 # ======================================================================
@@ -297,23 +311,22 @@ def scatter_call(x: jax.Array, dst: jax.Array, keep: jax.Array,
 # ======================================================================
 def _combine_kernel(y_ref, dst_ref, keep_ref, slot_ref, w_ref, out_ref, *,
                     capacity: int, block_t: int):
-    tb = pl.program_id(0)
-    s = pl.program_id(1)
+    s = pl.program_id(2)
 
     @pl.when(s == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    y = y_ref[0]                                              # [C, D]
-    mine = ((dst_ref[0] == s) & (keep_ref[0] > 0))            # [bT]
-    slot = slot_ref[0]
-    w = w_ref[0]                                              # [bT] f32
-    sel = (((slot[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block_t, capacity), 1))
-        & mine[:, None]).astype(jnp.float32) * w[:, None])    # [bT, C]
-    out_ref[...] += jax.lax.dot_general(
-        sel, y.astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(out_ref.dtype)
+    y = y_ref[...]                                            # [C, tD]
+    mine = (dst_ref[...] == s) & (keep_ref[...] > 0)          # [1, bT]
+    sel = ((jax.lax.broadcasted_iota(jnp.int32, (capacity, block_t), 0)
+            == slot_ref[...]) & mine).astype(y.dtype)         # [C, bT]
+    rows = jax.lax.dot_general(                               # [bT, tD]
+        sel, y, (((0,), (0,)), ((), ())), precision=_exact(y.dtype),
+        preferred_element_type=jnp.float32)
+    # Weights scale on the VPU in f32, as the XLA combine does: through
+    # the MXU they would be rounded to bf16 first.
+    out_ref[...] += (rows * w_ref[...]).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -325,21 +338,20 @@ def combine_call(y: jax.Array, dst: jax.Array, keep: jax.Array,
     S, C, D = y.shape
     T = dst.shape[0]
     nb = T // block_t
+    td = _d_tile(D)
     kernel = functools.partial(_combine_kernel, capacity=C, block_t=block_t)
+    row = _row_spec(block_t, lambda i, j, s: (i, 0, 0))
+    col = pl.BlockSpec((pl.squeezed, block_t, 1), lambda i, j, s: (i, 0, 0))
     return pl.pallas_call(
         kernel,
-        grid=(nb, S),
-        in_specs=[
-            pl.BlockSpec((1, C, D), lambda i, s: (s, 0, 0)),
-            pl.BlockSpec((1, block_t), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda i, s: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_t, D), lambda i, s: (i, 0)),
+        grid=(nb, D // td, S),
+        in_specs=[pl.BlockSpec((pl.squeezed, C, td),
+                               lambda i, j, s: (s, 0, j)),
+                  row, row, row, col],
+        out_specs=pl.BlockSpec((block_t, td), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((T, D), y.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(y, dst.reshape(nb, block_t), keep.reshape(nb, block_t),
-      slot.reshape(nb, block_t), weights.astype(jnp.float32).reshape(nb, block_t))
+    )(y, _rows(dst, block_t), _rows(keep, block_t), _rows(slot, block_t),
+      weights.astype(jnp.float32).reshape(nb, block_t, 1))

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -147,7 +145,7 @@ def flash_attention_hm(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
             pltpu.VMEM((block_q, D), jnp.float32),     # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 PARITY_POS = (1, 2, 4, 8, 16)
 DATA_POS = tuple(p for p in range(1, 32) if p not in PARITY_POS)
 COVER_MASKS = tuple(
@@ -100,7 +98,7 @@ def _call_elementwise(kernel, x: jax.Array, n_out: int, interpret: bool):
     return pl.pallas_call(
         kernel, grid=grid, in_specs=[spec], out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret)(x)
 

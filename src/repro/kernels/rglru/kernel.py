@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _rglru_kernel(a_ref, b_ref, h_ref, hlast_ref, carry, *, chunk: int):
     ci = pl.program_id(2)
@@ -86,7 +84,7 @@ def rglru_call(a: jax.Array, b: jax.Array, *, chunk: int = 256,
             jax.ShapeDtypeStruct((Bsz, 1, L), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_l), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dA_ref, dt_ref, b_ref, c_ref, y_ref, hlast_ref,
                 h_scratch, *, chunk: int):
@@ -108,7 +106,7 @@ def ssd_call(x: jax.Array, dA: jax.Array, dt: jax.Array, Bm: jax.Array,
             jax.ShapeDtypeStruct((Bsz, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dA, dt, Bm, Cm)

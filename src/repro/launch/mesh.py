@@ -8,6 +8,16 @@ initialisation, and smoke tests must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: shardings propagate through the
+    partitioner, so ``jit`` and ``shard_map`` over this mesh need no
+    ``jax.set_mesh`` context (``jax.make_mesh`` defaults to Explicit axes,
+    which type every array by its sharding)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,12 +25,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     2-pod data-parallel axis (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over whatever devices the host actually has (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 MESH_NAMES = {"pod": False, "multipod": True}

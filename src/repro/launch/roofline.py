@@ -12,8 +12,9 @@ cost_analysis: we parse the partitioned HLO text and apply ring-algorithm
 movement factors per op (all-reduce moves ~2x its payload, gather/scatter
 ~1x, all-to-all/permute ~1x of the local shard).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (per direction).
+Hardware constants come from ``CHIP_PEAKS``, keyed by the target chip's
+``jax.Device.device_kind``; a kind that is not in the table is an error,
+never a default.
 """
 from __future__ import annotations
 
@@ -22,9 +23,32 @@ import json
 import re
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (per direction)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float             # bf16 FLOP/s per chip
+    hbm_bw: float            # HBM bytes/s per chip
+    ici_bw: float            # ICI bytes/s per link (per direction)
+
+
+# Published per-chip peaks keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" is TPU v5e — Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect (4 links -> 50 GB/s per link per direction).
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peak table row for ``device_kind``; unknown kinds raise."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(CHIP_PEAKS)} (add a sourced row to CHIP_PEAKS)"
+        ) from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -97,18 +121,23 @@ class RooflineTerms:
     model_flops: Optional[float] = None          # 6*N*D (global)
     model_bytes: Optional[float] = None          # HBM floor (global), decode
     kind: str = "train"                          # train | prefill | decode
+    device_kind: str = "TPU v5 lite"             # target chip (CHIP_PEAKS)
+
+    @property
+    def peaks(self) -> ChipPeaks:
+        return chip_peaks(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_device / ICI_BW
+        return self.collective_bytes_per_device / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -147,11 +176,11 @@ class RooflineTerms:
         if self.kind == "decode":
             if not self.model_bytes:
                 return None
-            t_useful = self.model_bytes / (self.chips * HBM_BW)
+            t_useful = self.model_bytes / (self.chips * self.peaks.hbm_bw)
             return t_useful / self.roofline_s
         if not self.model_flops:
             return None
-        t_useful = self.model_flops / (self.chips * PEAK_FLOPS)
+        t_useful = self.model_flops / (self.chips * self.peaks.flops)
         return t_useful / self.roofline_s
 
     def to_dict(self) -> dict:
@@ -243,8 +272,6 @@ def dense_routing_bytes(hlo_text: str, tokens: int, ports_x_capacity: int,
 def extract(compiled, lowered=None) -> Tuple[float, float, Dict, Optional[float]]:
     """(flops, bytes, collectives, peak_mem) from a compiled artifact."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     text = compiled.as_text()

@@ -151,5 +151,5 @@ def lower_step(bundle: StepBundle, mesh: Mesh):
     fn = jax.jit(bundle.step, in_shardings=bundle.in_shardings,
                  out_shardings=bundle.out_shardings,
                  donate_argnums=bundle.donate_argnums)
-    with mesh:
+    with jax.set_mesh(mesh):
         return fn.lower(*bundle.arg_structs)

@@ -212,7 +212,12 @@ class LMBase:
         raise NotImplementedError
 
     def init(self, key: jax.Array) -> Params:
-        return init_params(self.param_defs(), key, self.dtype)
+        # One jitted program: each leaf's f32 draw fuses into its cast, so
+        # no full-size f32 copy of a leaf is ever held (eager init peaks at
+        # ~3x a bf16 leaf's bytes — more than a chip holds for an 8-expert
+        # d=4096 layer stack).
+        return jax.jit(
+            lambda k: init_params(self.param_defs(), k, self.dtype))(key)
 
     def param_specs(self, multi_pod: bool) -> Params:
         return spec_tree(self.param_defs(), multi_pod=multi_pod)

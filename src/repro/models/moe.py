@@ -617,9 +617,6 @@ def moe_forward_sharded(params, x: jax.Array, moe: MoEConfig, act: str, *,
 
     from repro.core.registers import CrossbarRegisters
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
     n = mesh.shape[axis_name]
     T = x.shape[0] * x.shape[1]
     cap = capacity if capacity is not None else expert_capacity(T, moe)
@@ -632,7 +629,7 @@ def moe_forward_sharded(params, x: jax.Array, moe: MoEConfig, act: str, *,
         in_specs.append(P())
         args.append(expert_mask)
 
-    @_ft.partial(shard_map, mesh=mesh, in_specs=tuple(in_specs),
+    @_ft.partial(jax.shard_map, mesh=mesh, in_specs=tuple(in_specs),
                  out_specs=(P(axis_name), P()))
     def run(p, xs, regs, *mask):
         return moe_apply_sharded(
@@ -640,4 +637,5 @@ def moe_forward_sharded(params, x: jax.Array, moe: MoEConfig, act: str, *,
             expert_mask=mask[0] if mask else None, capacity=cap,
             kernel_mode=kernel_mode)
 
-    return run(*args)
+    # Under jit, shard_map runs on Explicit and Auto meshes alike.
+    return jax.jit(run)(*args)

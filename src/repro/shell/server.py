@@ -315,6 +315,11 @@ class ElasticServer:
         """
         self._engines[app_id] = engine
 
+    def engine(self, app_id: int) -> Any:
+        """The engine registered for ``app_id`` (its model and params, for
+        callers that check what the server decodes)."""
+        return self._engines[app_id]
+
     # ---- request path -------------------------------------------------
     def submit(self, request: StreamRequest) -> int:
         """Enqueue a request; returns its server-assigned request id."""

@@ -456,7 +456,6 @@ def test_sharded_masking_parity_with_reference():
     the same per-packet verdicts and counts as the reference plan."""
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.registers import CrossbarRegisters
 from repro.fabric import Fabric
@@ -485,7 +484,7 @@ def body(r, d, s):
     plan = sharded.plan(d, s, registers=r)
     return plan.keep, plan.error, plan.counts, plan.drops
 
-run = jax.jit(shard_map(body, mesh=mesh,
+run = jax.jit(jax.shard_map(body, mesh=mesh,
                         in_specs=(P(), P("x"), P("x")),
                         out_specs=(P("x"), P("x"), P(), P())))
 keep, err, counts, drops = run(regs, dst, src)

@@ -5,11 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# jax<0.5 ships shard_map under jax.experimental; newer jax exposes it as
-# jax.shard_map.  Resolve once so the mesh tests run on both.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
 
 from repro.core.arbiter import combine, dispatch, wrr_dispatch_plan
 from repro.core.crossbar import (CrossbarInterconnect, combine_local,
@@ -76,7 +71,8 @@ class TestShardedExchange:
         if jax.device_count() < 4:
             pytest.skip("needs 4 local devices (run under "
                         "XLA_FLAGS=--xla_force_host_platform_device_count)")
-        return jax.make_mesh((4,), ("region",))
+        from repro.launch.mesh import make_mesh
+        return make_mesh((4,), ("region",))
 
     def test_exchange_sharded_routes_across_regions(self, mesh):
         from functools import partial
@@ -91,7 +87,7 @@ class TestShardedExchange:
         x = jnp.arange(n * Tloc * D, dtype=jnp.float32).reshape(n * Tloc, D)
         dst_global = (jnp.repeat(jnp.arange(n), Tloc) + 1) % n
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P("region"), P("region")),
                  out_specs=(P("region"), P("region")))
         def run(xs, ds):
@@ -126,7 +122,7 @@ class TestShardedExchange:
         dst = jnp.where(jnp.arange(n * Tloc) < Tloc, 3,
                         (jnp.repeat(jnp.arange(n), Tloc) + 1) % n)
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P("region"), P("region")),
                  out_specs=P("region"))
         def run(xs, ds):

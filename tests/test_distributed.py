@@ -43,14 +43,12 @@ def test_sharded_fabric_backend_plan_equivalent_on_4_devices():
     code = """
 import functools, numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
 from repro.core.registers import CrossbarRegisters
 from repro.fabric import Fabric
 
 n, Tloc, D, cap = 4, 12, 8, 16
-mesh = jax.make_mesh((n,), ("region",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((n,), ("region",))
 for seed in range(4):
     rng = np.random.default_rng(seed)
     regs = CrossbarRegisters(
@@ -67,7 +65,7 @@ for seed in range(4):
     fs = Fabric(regs, backend="sharded", capacity=cap, axis_name="region")
     fr = Fabric(regs, backend="reference", capacity=cap)
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P("region"), P("region"), P("region")),
                        out_specs=(P("region"), P("region"), P("region"),
                                   P("region"), P(), P()))
@@ -99,10 +97,11 @@ def test_train_step_lowers_on_4_device_mesh():
 import jax, jax.numpy as jnp
 import dataclasses
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_step, lower_step
 from repro.models.config import ShapeConfig
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 cfg = get_config("tinyllama_1_1b", smoke=True)
 shape = ShapeConfig("tiny_train", 64, 4, "train")
 bundle = build_step(cfg, shape, mesh, multi_pod=False)
@@ -111,7 +110,6 @@ compiled = lowered.compile()
 text = compiled.as_text()
 assert "all-reduce" in text, "expected DP gradient all-reduce"
 ca = compiled.cost_analysis()
-ca = ca[0] if isinstance(ca, (list, tuple)) else ca   # jax<0.5: per-device list
 print("LOWER_OK", ca["flops"] > 0)
 """
     res = run_with_devices(code)
@@ -123,10 +121,11 @@ def test_moe_train_step_lowers_with_expert_parallel_collectives():
     code = """
 import jax
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_step, lower_step
 from repro.models.config import ShapeConfig
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 cfg = get_config("mixtral_8x7b", smoke=True)
 shape = ShapeConfig("tiny_train", 64, 4, "train")
 bundle = build_step(cfg, shape, mesh, multi_pod=False)
@@ -144,17 +143,16 @@ def test_decode_step_lowers_and_runs_on_4_devices():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.lm import build_model
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 cfg = get_config("granite_3_2b", smoke=True)
 model = build_model(cfg)
 params = model.init(jax.random.key(0))
 state = model.init_decode_state(4, 32)
 batch = {"tokens": jnp.zeros((4, 1), jnp.int32)}
-set_mesh = getattr(jax, "set_mesh", None)
-ctx = set_mesh(mesh) if set_mesh is not None else mesh   # jax<0.5: Mesh is a ctx manager
-with ctx:
+with jax.set_mesh(mesh):
     logits, state2 = jax.jit(model.decode_step)(params, state, batch)
 assert not bool(jnp.isnan(logits.astype(jnp.float32)).any())
 assert int(state2.pos) == 1

@@ -419,11 +419,9 @@ from jax.sharding import PartitionSpec as P
 from repro.core.registers import CrossbarRegisters
 from repro.fabric.backends import ShardedBackend
 
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
 
-mesh = jax.make_mesh((4,), ("r",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("r",))
 regs = CrossbarRegisters.create(4, capacity=6)
 be = ShardedBackend("r")
 C = 6
@@ -445,9 +443,9 @@ def ticks(x0, x1, dst, w):
             be.combine(y1, plan, w, route=route),
             plan.keep)
 
-f = shard_map(ticks, mesh=mesh,
+f = jax.shard_map(ticks, mesh=mesh,
               in_specs=(P("r"), P("r"), P("r"), P("r")),
-              out_specs=(P("r"),) * 5, check_rep=False)
+              out_specs=(P("r"),) * 5, check_vma=False)
 a0, r0, a1, r1, keep = (np.asarray(v) for v in f(x0, x1, dst, w))
 np.testing.assert_array_equal(a0, r0)
 np.testing.assert_array_equal(a1, r1)

@@ -13,7 +13,7 @@ ISSUE 6 acceptance criteria, negative path first:
   env var over the whole test suite stays green;
 - in-trace callers opt in explicitly and functionalize the checks
   themselves (``checkify.checkify`` around the outer jit; ``shard_map``
-  bodies with ``check_rep=False``).
+  bodies with ``check_vma=False``).
 """
 import os
 import subprocess
@@ -220,11 +220,10 @@ def test_debug_mode_keeps_single_trace():
 
 def test_sharded_debug_on_forced_mesh():
     """All three ISSUE fault paths on the sharded backend, inside
-    shard_map(check_rep=False) under an outer checkify."""
+    jax.shard_map(check_vma=False) under an outer checkify."""
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.experimental import checkify
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.registers import CrossbarRegisters
 from repro.fabric import Fabric
@@ -244,8 +243,8 @@ def body_plain(r, x, d, s):
 
 kw = dict(mesh=mesh, in_specs=(P(), P("x"), P("x"), P("x")),
           out_specs=(P("x"), P()))
-run = checkify.checkify(jax.jit(shard_map(body, check_rep=False, **kw)))
-run_plain = jax.jit(shard_map(body_plain, **kw))
+run = checkify.checkify(jax.jit(jax.shard_map(body, check_vma=False, **kw)))
+run_plain = jax.jit(jax.shard_map(body_plain, **kw))
 
 x = jnp.arange(8 * 8, dtype=jnp.float32).reshape(8, 8)
 dst = jnp.asarray([0, 1, 2, 3] * 2)
@@ -287,7 +286,6 @@ def test_sharded_dest_sprayer_strict_vs_masked_on_forced_mesh():
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.experimental import checkify
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.registers import CrossbarRegisters
 from repro.fabric import Fabric
@@ -323,8 +321,8 @@ def body(fab):
 kw = dict(mesh=mesh, in_specs=(P(), P("x"), P("x"), P("x")),
           out_specs=(P("x"), P("x"), P("x"), P()))
 run_strict = checkify.checkify(
-    jax.jit(shard_map(body(strict), check_rep=False, **kw)))
-run_plain = jax.jit(shard_map(body(plain), **kw))
+    jax.jit(jax.shard_map(body(strict), check_vma=False, **kw)))
+run_plain = jax.jit(jax.shard_map(body(plain), **kw))
 
 err, _ = run_strict(regs, x, honest, src)
 assert err.get() is None, err.get()          # clean traffic passes strict
@@ -335,7 +333,7 @@ assert err.get() and "invalid destination" in err.get(), err.get()
 # normal mode: masked, bit-identical under a second debug-off build
 plain2 = Fabric(regs, backend="sharded", axis_name="x", capacity=4,
                 debug=False)
-run_plain2 = jax.jit(shard_map(body(plain2), **kw))
+run_plain2 = jax.jit(jax.shard_map(body(plain2), **kw))
 y0, keep0, err0, drops0 = run_plain(regs, x, spray, src)
 y1, keep1, err1, drops1 = run_plain2(regs, x, spray, src)
 for a, b in ((y0, y1), (keep0, keep1), (err0, err1), (drops0, drops1)):

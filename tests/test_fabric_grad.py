@@ -343,7 +343,6 @@ class TestMoEGrad:
 def test_sharded_grad_matches_reference_on_forced_mesh():
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.registers import CrossbarRegisters
 from repro.fabric import Fabric
@@ -366,7 +365,7 @@ def body(r, x, w, d, s):
 
 kw = dict(mesh=mesh, in_specs=(P(), P("x"), P("x"), P("x"), P("x")),
           out_specs=P("x"))
-run = shard_map(body, check_rep=False, **kw)
+run = jax.shard_map(body, check_vma=False, **kw)
 
 def loss(x, w, r=regs):
     return jnp.sum(run(r, x, w, dst, src) * probe)

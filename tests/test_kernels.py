@@ -99,6 +99,65 @@ def test_crossbar_kernels_match_ref(T, S, C, D, block_t):
                                                       w)), atol=1e-5)
 
 
+@pytest.mark.parametrize("T,n,D,block_t", [
+    (1024, 8, 1024, 256),       # 4 token blocks, 2 D tiles
+    (640, 5, 256, 128),         # 5 token blocks, odd port count
+])
+def test_crossbar_kernels_match_ref_across_blocks_with_binding_quotas(
+        T, n, D, block_t):
+    """The fused plan and the kernel data plane over several token blocks
+    (nb > 1), with WRR quotas that bind mid-stream: the per-pair live
+    counts carried between blocks must reproduce ``ref.plan_multi_ref``
+    bit for bit, and the backend's slabs and combine must equal the
+    ``core/arbiter`` scatter/gather of the oracle plan."""
+    from repro.core import arbiter
+    from repro.core.registers import CrossbarRegisters, ErrorCode
+    from repro.fabric import Fabric
+    from repro.kernels.crossbar_dispatch.kernel import plan_multi_call
+
+    rng = np.random.default_rng(T + n)
+    dst = jnp.asarray(rng.integers(-1, n, T), jnp.int32)
+    src = jnp.asarray(rng.integers(0, n, T), jnp.int32)
+    allowed = jnp.asarray(rng.random((n, n)) > 0.2)
+    quota = jnp.asarray(rng.integers(1, T // (n * n), (n, n)), jnp.int32)
+    assert T // block_t > 1
+
+    got = plan_multi_call(dst, src, allowed.astype(jnp.int32), quota.T,
+                          n_ports=n, block_t=block_t, interpret=True)
+    want = xref.plan_multi_ref(dst, src, allowed, quota.T, block_t)
+    for name, g, w in zip(("keep", "rank", "err", "granted"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
+    err, rank = np.asarray(got[2]), np.asarray(got[1])
+    assert (err == ErrorCode.GRANT_TIMEOUT).sum() > 0      # quotas bind
+    # Ranks past the first block exceed any count one block can hold, so
+    # the carried per-pair counters shaped them.
+    d = np.asarray(dst)
+    pair = np.where(d >= 0, np.asarray(src) * n + d, -1).reshape(-1, block_t)
+    in_block = max(np.bincount(p[p >= 0]).max() for p in pair)
+    assert rank[block_t:].max() >= in_block
+
+    C = 8 * (T // (8 * n))
+    regs = CrossbarRegisters.create(n, capacity=C).write(allowed=allowed,
+                                                         quota=quota)
+    fab = Fabric(regs, backend="pallas", data_plane="kernel",
+                 block_t=block_t, kernel_mode="pallas_interpret",
+                 capacity=C)
+    x = jnp.asarray(rng.standard_normal((T, D)), jnp.float32)
+    w = jnp.asarray(rng.random(T), jnp.float32)
+    slabs, plan = fab.dispatch(x, dst, src)
+    oracle = arbiter.wrr_dispatch_plan(dst, src, regs)
+    for name in ("keep", "slot", "error", "counts", "drops"):
+        np.testing.assert_array_equal(np.asarray(getattr(plan, name)),
+                                      np.asarray(getattr(oracle, name)),
+                                      err_msg=name)
+    np.testing.assert_array_equal(
+        np.asarray(slabs), np.asarray(arbiter.dispatch(x, oracle, n, C)))
+    np.testing.assert_array_equal(
+        np.asarray(fab.combine(slabs, plan, weights=w)),
+        np.asarray(arbiter.combine(slabs, oracle, w)))
+
+
 def test_crossbar_plan_matches_core_pairwise_plan():
     """Kernel semantics == the shard_map production path's plan."""
     from repro.core.crossbar import pairwise_dispatch_plan

@@ -59,7 +59,8 @@ params = init_params(moe_defs(d, dff, moe, "swiglu"),
                      jax.random.key(0), jnp.float32)
 B, S = 8, 16
 x = jax.random.normal(jax.random.key(1), (B, S, d))
-mesh = jax.make_mesh((4,), ("expert",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("expert",))
 
 # ample capacity: the sharded path reproduces the dense formulation
 cap = expert_capacity(B * S, moe)
@@ -137,7 +138,8 @@ d = 16
 params = init_params(moe_defs(d, 32, moe, "swiglu"),
                      jax.random.key(0), jnp.float32)
 x = jax.random.normal(jax.random.key(1), (4, 8, d))
-mesh = jax.make_mesh((4,), ("expert",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("expert",))
 CAP = 64
 
 step = jax.jit(lambda p, regs, xx: moe_forward_sharded(

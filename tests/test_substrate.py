@@ -152,7 +152,8 @@ class TestCheckpoint:
         """Restore re-places leaves with explicit shardings (the region-
         reprogram path)."""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((1,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1,), ("data",))
         t = {"w": jnp.arange(8, dtype=jnp.float32)}
         save_checkpoint(tmp_path, 1, t)
         sh = {"w": NamedSharding(mesh, P("data"))}
@@ -249,3 +250,30 @@ class TestTrainLoop:
         loop2 = TrainLoop(cfg, run, ckpt_dir=tmp_path, resume=True)
         assert loop2.start_step == 40
         assert loop2.pipeline.state().step == 40
+
+
+# ----------------------------------------------------------------------
+# roofline peak table
+# ----------------------------------------------------------------------
+class TestRooflinePeaks:
+    def test_terms_use_the_target_chips_published_peaks(self):
+        from repro.launch.roofline import RooflineTerms, chip_peaks
+
+        v5e = chip_peaks("TPU v5 lite")
+        assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+        t = RooflineTerms(arch="a", shape="s", mesh="pod", chips=1,
+                          flops_per_device=197e12, bytes_per_device=819e9,
+                          collective_bytes_per_device=0.0, collectives={})
+        assert t.t_compute == t.t_memory == 1.0
+
+    def test_unknown_device_kind_is_an_error(self):
+        from repro.launch.roofline import RooflineTerms, chip_peaks
+
+        with pytest.raises(ValueError, match="no published peaks"):
+            chip_peaks("cpu")
+        t = RooflineTerms(arch="a", shape="s", mesh="pod", chips=1,
+                          flops_per_device=1.0, bytes_per_device=1.0,
+                          collective_bytes_per_device=0.0, collectives={},
+                          device_kind="TPU v9")
+        with pytest.raises(ValueError):
+            t.roofline_s

@@ -180,10 +180,12 @@ _ARRAYISH_NAMES = {
 _STATIC_ATTRS = {"shape", "ndim", "dtype", "size", "n_ports", "aval",
                  "sharding", "weak_type"}
 # Calls whose result is static regardless of argument taint
-# (``jnp.issubdtype`` inspects dtypes, never values).
+# (``jnp.issubdtype`` inspects dtypes, never values; ``jax.lax.axis_size``
+# is the mesh axis length, a Python int).
 _STATIC_CALLS = {"len", "isinstance", "issubclass", "type", "hasattr",
                  "getattr", "id", "repr", "str", "range", "enumerate",
-                 "zip", "issubdtype", "result_type", "can_cast"}
+                 "zip", "issubdtype", "result_type", "can_cast",
+                 "axis_size"}
 _CONCRETIZE_CALLS = {"int", "float", "bool", "complex"}
 _CONCRETIZE_METHODS = {"item", "tolist", "__index__"}
 _ASARRAY_RE = re.compile(r"^(np|numpy)\.(asarray|array|asanyarray)$")
