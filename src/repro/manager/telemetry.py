@@ -40,6 +40,9 @@ channel                 value
 ``plan_cache_hits``     cumulative fabric plan-cache hits (int)
 ``plan_cache_misses``   cumulative fabric plan-cache misses (int)
 ``plan_cache_invalidations``  cumulative epoch flushes of live entries
+``engine_syncs``        cumulative device->host token reads by the
+                        server's engines (int; one per ``ModelEngine``
+                        prefill group or decode call)
 ======================  ================================================
 
 Dict channels merge across probes (per-key update), scalar/array channels
@@ -289,6 +292,7 @@ class ServerProbe:
             "masked_by_src": tuple(int(v) for v in srv.masked_by_src),
             "dropped_by_src": tuple(int(v) for v in srv.dropped_by_src),
             "fabric_traces": int(srv.fabric.trace_count),
+            "engine_syncs": int(srv.engine_syncs),
         }
         if getattr(srv.fabric, "plan_cache", None) is not None:
             ch.update(srv.fabric.plan_cache.stats())

@@ -10,6 +10,9 @@ sub-quadratic rather than masked-quadratic.
 The Pallas flash kernel (``repro.kernels.flash_attention``) implements the
 same contract for the TPU deploy path; this module is the XLA fallback used
 by the CPU dry-run and the kernel's oracle.
+
+Both paths run under the op-name scope ``attn.core``, so a device trace can
+time attention apart from the projections around it.
 """
 from __future__ import annotations
 
@@ -37,6 +40,7 @@ def _gqa_out(p: jax.Array, v: jax.Array) -> jax.Array:
     return jnp.einsum("bkgqs,bskd->bkgqd", p, v.astype(jnp.float32))
 
 
+@jax.named_scope("attn.core")
 def attention_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool = True, window: Optional[int] = None,
                       q_chunk: int = 512, kv_chunk: int = 1024,
@@ -162,6 +166,7 @@ def cache_write(cache_k: jax.Array, cache_v: jax.Array, positions: jax.Array,
     return ck, cv, pp
 
 
+@jax.named_scope("attn.core")
 def attention_decode(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                      slot_positions: jax.Array, pos: jax.Array,
                      window: Optional[int] = None) -> jax.Array:

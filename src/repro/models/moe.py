@@ -231,15 +231,8 @@ def moe_apply_gather(params, x: jax.Array, moe: MoEConfig, act: str, *,
     slabs = jax.vmap(fill)(slabs, slot_addr, xk)
     xe = slabs[:, :E * cap].reshape(G, E, cap, d)
 
-    h = jnp.einsum("gecd,edf->gecf", xe, params["w_in"])
-    if act in ("swiglu", "geglu"):
-        gate, up = jnp.split(h, 2, axis=-1)
-        a = jax.nn.silu(gate.astype(jnp.float32)) if act == "swiglu" \
-            else jax.nn.gelu(gate.astype(jnp.float32))
-        h = (a * up.astype(jnp.float32)).astype(x.dtype)
-    else:
-        h = jax.nn.gelu(h.astype(jnp.float32)).astype(x.dtype)
-    ye = jnp.einsum("gecf,efd->gecd", h, params["w_out"])  # [G, E, cap, d]
+    ye = jax.vmap(lambda xg: _expert_ffn(xg, params["w_in"],
+                                         params["w_out"], act))(xe)
 
     # --- indexed combine: gather each packet's result, weight, sum top-k -
     ye_flat = ye.reshape(G, E * cap, d)
@@ -339,10 +332,13 @@ def _moe_router(params, xf: jax.Array, moe: MoEConfig,
     return dst, w, probs
 
 
+@jax.named_scope("moe.expert_ffn")
 def _expert_ffn(slabs: jax.Array, w_in: jax.Array, w_out: jax.Array,
                 act: str) -> jax.Array:
     """The expert MLP over receive slabs [E?, C, d] (any expert count —
-    the sharded path passes each shard's local expert block)."""
+    the sharded path passes each shard's local expert block).  Its ops
+    carry the op-name scope ``moe.expert_ffn`` in every path, so a device
+    trace can time the expert layer."""
     h = jnp.einsum("ecd,edf->ecf", slabs, w_in)
     if act in ("swiglu", "geglu"):
         gate, up = jnp.split(h, 2, axis=-1)

@@ -34,6 +34,13 @@ tests inject lightweight fakes via ``register_engine`` (anything with
 state), ...]`` opts into fused admission, and an optional
 ``decode_batch(toks, states) -> (toks, states)`` opts into fused
 per-tick decode across slots — elementwise-identical to the loop).
+
+The tick is traced with ``jax.profiler.TraceAnnotation`` host spans, which
+cost about a microsecond each while no profiler runs: ``server.tick``
+holds ``server.admit`` (one ``server.prefill`` per admission group),
+``server.route`` and one ``server.decode`` per engine call; a
+``ModelEngine`` adds ``engine.sync`` (the device->host read of the
+tokens) and ``engine.split``.  A request's spans carry its ``rid``.
 """
 from __future__ import annotations
 
@@ -43,6 +50,7 @@ import itertools
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.shell.shell import Shell
 
@@ -97,6 +105,9 @@ class ModelEngine:
         self._prefill_fns: "collections.OrderedDict[Tuple[int, int], Any]" \
             = collections.OrderedDict()
         self._prefill_cache_max = 16
+        # Device->host token reads (one per ``_greedy`` call), cumulative;
+        # ``ServerProbe`` reports them as the ``engine_syncs`` channel.
+        self.engine_syncs = 0
 
         def decode_one(params, state, batch_):
             return self.model.decode_step(params, state, batch_)
@@ -105,8 +116,10 @@ class ModelEngine:
 
     def _greedy(self, logits):
         from repro.runtime.serve import greedy_tokens
-        return [int(t) for t in np.asarray(greedy_tokens(logits,
-                                                         self.cfg.vocab))]
+        self.engine_syncs += 1
+        with TraceAnnotation("engine.sync", B=logits.shape[0]):
+            return [int(t) for t in np.asarray(greedy_tokens(logits,
+                                                             self.cfg.vocab))]
 
     def _prefill_fn(self, B: int, S: int):
         """One jitted (scan-fused) batched replay per (B, S) shape."""
@@ -146,21 +159,22 @@ class ModelEngine:
         if B == 1:
             return [state]
         jax = self._jax
-        ref1 = jax.eval_shape(
-            lambda: self.model.init_decode_state(1, self.max_len))
-        refb = jax.eval_shape(
-            lambda: self.model.init_decode_state(B, self.max_len))
+        with TraceAnnotation("engine.split", B=B):
+            ref1 = jax.eval_shape(
+                lambda: self.model.init_decode_state(1, self.max_len))
+            refb = jax.eval_shape(
+                lambda: self.model.init_decode_state(B, self.max_len))
 
-        def slice_i(i):
-            def leaf(x, s1, sb):
-                axes = [a for a, (d1, db) in
-                        enumerate(zip(s1.shape, sb.shape)) if d1 != db]
-                if not axes:
-                    return x                                # shared (pos)
-                return jax.lax.index_in_dim(x, i, axes[0], keepdims=True)
-            return jax.tree_util.tree_map(leaf, state, ref1, refb)
+            def slice_i(i):
+                def leaf(x, s1, sb):
+                    axes = [a for a, (d1, db) in
+                            enumerate(zip(s1.shape, sb.shape)) if d1 != db]
+                    if not axes:
+                        return x                            # shared (pos)
+                    return jax.lax.index_in_dim(x, i, axes[0], keepdims=True)
+                return jax.tree_util.tree_map(leaf, state, ref1, refb)
 
-        return [slice_i(i) for i in range(B)]
+            return [slice_i(i) for i in range(B)]
 
     def prefill_batch(self, prompts) -> List[Tuple[int, Any]]:
         """Fused admission prefill for same-length prompts (one call)."""
@@ -273,6 +287,13 @@ class ElasticServer:
     def dropped_by_src(self) -> np.ndarray:
         """All non-granted offers per originating source port."""
         return self.fabric.dropped_by_src
+
+    @property
+    def engine_syncs(self) -> int:
+        """Device->host token reads by the registered engines that count
+        them (``ModelEngine.engine_syncs``)."""
+        return sum(getattr(e, "engine_syncs", 0)
+                   for e in self._engines.values())
 
     # ---- engines ------------------------------------------------------
     def register_model(self, app_id: int, cfg, *, max_len: int = 128,
@@ -395,54 +416,59 @@ class ElasticServer:
         per-request ``prefill``)."""
         if not self.queue:
             return 0                # steady state: skip the free-slot scan
-        free = [i for i, slot in enumerate(self.slots) if slot is None]
-        picked: List[Tuple[int, StreamRequest, int]] = []
-        blocked: List[StreamRequest] = []
-        holding: Dict[int, int] = {}
-        if self.slots_per_region is not None:
-            for slot in self.slots:
-                if slot is not None:
-                    app = slot.request.app_id
-                    holding[app] = holding.get(app, 0) + 1
-        while free and self.queue:
-            cand = self.queue.popleft()
-            port = self.shell.route(cand.app_id)
-            if port is None:
-                # Tenant not admitted to the shell (yet): park it and try
-                # the next request — the control plane gates entry.
-                blocked.append(cand)
-                continue
+        with TraceAnnotation("server.admit") as span:
+            free = [i for i, slot in enumerate(self.slots) if slot is None]
+            picked: List[Tuple[int, StreamRequest, int]] = []
+            blocked: List[StreamRequest] = []
+            holding: Dict[int, int] = {}
             if self.slots_per_region is not None:
-                # Grant-coupled capacity: regions buy concurrency (every
-                # tenant keeps one on-server slot so nobody starves).
-                t = self.shell.state.tenant_by_app(cand.app_id)
-                placed = t.placed_count if t is not None else 0
-                limit = max(1, placed * self.slots_per_region)
-                if holding.get(cand.app_id, 0) >= limit:
+                for slot in self.slots:
+                    if slot is not None:
+                        app = slot.request.app_id
+                        holding[app] = holding.get(app, 0) + 1
+            while free and self.queue:
+                cand = self.queue.popleft()
+                port = self.shell.route(cand.app_id)
+                if port is None:
+                    # Tenant not admitted to the shell (yet): park it and
+                    # try the next request — the control plane gates entry.
                     blocked.append(cand)
                     continue
-                holding[cand.app_id] = holding.get(cand.app_id, 0) + 1
-            picked.append((free.pop(0), cand, port))
-        self.queue.extendleft(reversed(blocked))
+                if self.slots_per_region is not None:
+                    # Grant-coupled capacity: regions buy concurrency (every
+                    # tenant keeps one on-server slot so nobody starves).
+                    t = self.shell.state.tenant_by_app(cand.app_id)
+                    placed = t.placed_count if t is not None else 0
+                    limit = max(1, placed * self.slots_per_region)
+                    if holding.get(cand.app_id, 0) >= limit:
+                        blocked.append(cand)
+                        continue
+                    holding[cand.app_id] = holding.get(cand.app_id, 0) + 1
+                picked.append((free.pop(0), cand, port))
+            self.queue.extendleft(reversed(blocked))
+            span.set_metadata(admitted=len(picked))
 
-        groups: Dict[Tuple[int, int], List[Tuple[int, StreamRequest, int]]]
-        groups = {}
-        for item in picked:
-            _, req, _ = item
-            groups.setdefault((req.app_id, len(req.prompt)),
-                              []).append(item)
-        for (app_id, _), items in groups.items():
-            engine = self._engines[app_id]
-            batch_fn = getattr(engine, "prefill_batch", None)
-            if batch_fn is not None:
-                results = batch_fn([req.prompt for _, req, _ in items])
-            else:
-                results = [engine.prefill(req.prompt)
-                           for _, req, _ in items]
-            for (i, req, port), (tok, state) in zip(items, results):
-                self.slots[i] = _Slot(request=req, entry_port=port,
-                                      admitted_tick=self.tick, state=state,
-                                      next_tok=tok)
+            groups: Dict[Tuple[int, int],
+                         List[Tuple[int, StreamRequest, int]]] = {}
+            for item in picked:
+                _, req, _ = item
+                groups.setdefault((req.app_id, len(req.prompt)),
+                                  []).append(item)
+            for (app_id, S), items in groups.items():
+                engine = self._engines[app_id]
+                batch_fn = getattr(engine, "prefill_batch", None)
+                rids = ";".join(str(req.rid) for _, req, _ in items)
+                with TraceAnnotation("server.prefill", app=app_id,
+                                     B=len(items), S=S, rids=rids):
+                    if batch_fn is not None:
+                        results = batch_fn([req.prompt for _, req, _ in items])
+                    else:
+                        results = [engine.prefill(req.prompt)
+                                   for _, req, _ in items]
+                for (i, req, port), (tok, state) in zip(items, results):
+                    self.slots[i] = _Slot(request=req, entry_port=port,
+                                          admitted_tick=self.tick,
+                                          state=state, next_tok=tok)
         if picked:
             self._routes_dirty = True
             self._active += len(picked)
@@ -458,88 +484,94 @@ class ElasticServer:
         occupancy changes: the fabric's plan cache keys on their bytes
         directly, so a steady-state tick (same slots, same epoch) is a
         pure host-side lookup with no device round-trip."""
-        if self._routes_dirty:
-            dst = np.full(self.n_slots, -1, np.int32)
-            for i, slot in enumerate(self.slots):
-                if slot is not None:
-                    dst[i] = slot.entry_port
-            self._dst = dst
-            self._src = np.full(self.n_slots, self.shell.state.host_port,
-                                np.int32)
-            self._routes_dirty = False
-        plan = self.fabric.plan(self._dst, self._src)
-        # Padding slots (dst = -1) are dropped by design; only real slots
-        # count as offered load, so offered - granted is the true drop
-        # tally.  The fabric owns the cumulative counters; passing the
-        # source vector keys drops/masks to their originating port
-        # (server traffic originates at the host bridge).
-        self.fabric.account(plan, self._src)
+        with TraceAnnotation("server.route"):
+            if self._routes_dirty:
+                dst = np.full(self.n_slots, -1, np.int32)
+                for i, slot in enumerate(self.slots):
+                    if slot is not None:
+                        dst[i] = slot.entry_port
+                self._dst = dst
+                self._src = np.full(self.n_slots, self.shell.state.host_port,
+                                    np.int32)
+                self._routes_dirty = False
+            plan = self.fabric.plan(self._dst, self._src)
+            # Padding slots (dst = -1) are dropped by design; only real
+            # slots count as offered load, so offered - granted is the true
+            # drop tally.  The fabric owns the cumulative counters; passing
+            # the source vector keys drops/masks to their originating port
+            # (server traffic originates at the host bridge).
+            self.fabric.account(plan, self._src)
 
     def step(self) -> List[StreamCompletion]:
         """One server tick: admit, then one decode token per active slot."""
-        admitted = self._admit()
-        # A stall means this tick had nothing to do AND nothing could enter:
-        # every queued request is waiting on a control-plane event.  Slots
-        # that free at the end of this tick don't count — the next tick's
-        # admission pass gets first claim on them.
-        self._stalled = (bool(self.queue) and admitted == 0
-                         and self.active_count == 0)
-        if self.active_count:
-            self._account_traffic()
-        finished: List[StreamCompletion] = []
-        # Survivor grouping: per-app slot lists feed the fused decode pass.
-        # With a single registered engine (the high-QPS serving shape) the
-        # grouping collapses to one list append per slot — no dict hop.
-        one_app = len(self._engines) == 1
-        survivors: List[_Slot] = []
-        live: Dict[int, List[_Slot]] = {}
-        for i, slot in enumerate(self.slots):
-            if slot is None:
-                continue
-            slot.produced.append(slot.next_tok)
-            if len(slot.produced) >= slot.request.max_new:
-                comp = StreamCompletion(
-                    rid=slot.request.rid, app_id=slot.request.app_id,
-                    tokens=list(slot.produced), entry_port=slot.entry_port,
-                    admitted_tick=slot.admitted_tick,
-                    finished_tick=self.tick,
-                    submitted_tick=slot.request.submitted_tick)
-                self.completions.append(comp)
-                finished.append(comp)
-                self.slots[i] = None            # rotate: free on completion
-                self._routes_dirty = True
-                self._active -= 1
-                continue
-            if one_app:
-                survivors.append(slot)
-            else:
-                live.setdefault(slot.request.app_id, []).append(slot)
-        if one_app and survivors:
-            live[survivors[0].request.app_id] = survivors
-        # Decode pass: one fused ``decode_batch`` call per engine that
-        # offers it (the steady-state fast path — 1k slots advance in one
-        # call instead of 1k), per-slot ``decode`` otherwise.  Semantics
-        # are the engine's contract: elementwise-identical to the loop.
-        # ``decode_batch`` may return ``None`` for the states to mean
-        # "unchanged / managed in place" — the writeback is skipped.
-        for app_id, slots in live.items():
-            engine = self._engines[app_id]
-            batch_fn = getattr(engine, "decode_batch", None)
-            if batch_fn is not None and len(slots) > 1:
-                toks, states = batch_fn([s.next_tok for s in slots],
-                                        [s.state for s in slots])
-                if states is None:
-                    for slot, tok in zip(slots, toks):
-                        slot.next_tok = tok
+        with TraceAnnotation("server.tick", tick=self.tick):
+            admitted = self._admit()
+            # A stall means this tick had nothing to do AND nothing could
+            # enter: every queued request is waiting on a control-plane
+            # event.  Slots that free at the end of this tick don't count —
+            # the next tick's admission pass gets first claim on them.
+            self._stalled = (bool(self.queue) and admitted == 0
+                             and self.active_count == 0)
+            if self.active_count:
+                self._account_traffic()
+            finished: List[StreamCompletion] = []
+            # Survivor grouping: per-app slot lists feed the fused decode
+            # pass.  With a single registered engine (the high-QPS serving
+            # shape) the grouping collapses to one list append per slot —
+            # no dict hop.
+            one_app = len(self._engines) == 1
+            survivors: List[_Slot] = []
+            live: Dict[int, List[_Slot]] = {}
+            for i, slot in enumerate(self.slots):
+                if slot is None:
+                    continue
+                slot.produced.append(slot.next_tok)
+                if len(slot.produced) >= slot.request.max_new:
+                    comp = StreamCompletion(
+                        rid=slot.request.rid, app_id=slot.request.app_id,
+                        tokens=list(slot.produced), entry_port=slot.entry_port,
+                        admitted_tick=slot.admitted_tick,
+                        finished_tick=self.tick,
+                        submitted_tick=slot.request.submitted_tick)
+                    self.completions.append(comp)
+                    finished.append(comp)
+                    self.slots[i] = None        # rotate: free on completion
+                    self._routes_dirty = True
+                    self._active -= 1
+                    continue
+                if one_app:
+                    survivors.append(slot)
                 else:
-                    for slot, tok, state in zip(slots, toks, states):
-                        slot.next_tok, slot.state = tok, state
-            else:
-                for slot in slots:
-                    slot.next_tok, slot.state = engine.decode(slot.next_tok,
-                                                              slot.state)
-        self.tick += 1
-        return finished
+                    live.setdefault(slot.request.app_id, []).append(slot)
+            if one_app and survivors:
+                live[survivors[0].request.app_id] = survivors
+            # Decode pass: one fused ``decode_batch`` call per engine that
+            # offers it (the steady-state fast path — 1k slots advance in one
+            # call instead of 1k), per-slot ``decode`` otherwise.  Semantics
+            # are the engine's contract: elementwise-identical to the loop.
+            # ``decode_batch`` may return ``None`` for the states to mean
+            # "unchanged / managed in place" — the writeback is skipped.
+            for app_id, slots in live.items():
+                engine = self._engines[app_id]
+                batch_fn = getattr(engine, "decode_batch", None)
+                if batch_fn is not None and len(slots) > 1:
+                    with TraceAnnotation("server.decode", n=len(slots)):
+                        toks, states = batch_fn([s.next_tok for s in slots],
+                                                [s.state for s in slots])
+                    if states is None:
+                        for slot, tok in zip(slots, toks):
+                            slot.next_tok = tok
+                    else:
+                        for slot, tok, state in zip(slots, toks, states):
+                            slot.next_tok, slot.state = tok, state
+                else:
+                    for slot in slots:
+                        with TraceAnnotation("server.decode",
+                                             rid=slot.request.rid):
+                            slot.next_tok, slot.state = engine.decode(
+                                slot.next_tok, slot.state)
+            self.tick += 1
+            return finished
 
     def run(self, *, max_ticks: int = 10_000) -> List[StreamCompletion]:
         """Step until queue and slots drain, or until admission stalls
