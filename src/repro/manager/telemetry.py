@@ -43,6 +43,9 @@ channel                 value
 ``engine_syncs``        cumulative device->host token reads by the
                         server's engines (int; one per ``ModelEngine``
                         prefill group or decode call)
+``admission_replays``   cumulative prompts the server's engines admitted
+                        by token-by-token replay instead of one parallel
+                        prefill (int)
 ======================  ================================================
 
 Dict channels merge across probes (per-key update), scalar/array channels
@@ -293,6 +296,7 @@ class ServerProbe:
             "dropped_by_src": tuple(int(v) for v in srv.dropped_by_src),
             "fabric_traces": int(srv.fabric.trace_count),
             "engine_syncs": int(srv.engine_syncs),
+            "admission_replays": int(srv.admission_replays),
         }
         if getattr(srv.fabric, "plan_cache", None) is not None:
             ch.update(srv.fabric.plan_cache.stats())

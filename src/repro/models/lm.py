@@ -299,7 +299,11 @@ class DenseLM(LMBase):
         return out
 
     # ---- forward ------------------------------------------------------
-    def _block(self, lp, x, positions, moe_group: int):
+    def _block(self, lp, x, positions, moe_group: int,
+               moe_capacity: Optional[int] = None):
+        """One layer: (x, aux loss, (k, v, dropped)) — the layer's post-RoPE
+        keys and values and the packets its experts dropped, which only
+        ``prefill_cache`` keeps."""
         cfg = self.cfg
         x = self.constrain(x)
         h = rms_norm(x, lp["norm1"], cfg.norm_eps)
@@ -315,28 +319,36 @@ class DenseLM(LMBase):
             y, stats = moe_mod.moe_apply(lp["moe"], h2, cfg.moe, cfg.mlp_act,
                                          group_size=moe_group,
                                          dispatch_impl=cfg.moe.dispatch,
+                                         capacity=moe_capacity,
                                          kernel_mode=cfg.moe.kernel_mode)
-            aux = stats["aux_loss"]
+            aux, dropped = stats["aux_loss"], stats["dropped"]
         else:
             y = mlp_mod.mlp_apply(lp["mlp"], h2, cfg.mlp_act)
             aux = jnp.zeros((), jnp.float32)
-        return x + y, aux
+            dropped = jnp.zeros((), jnp.int32)
+        return x + y, aux, (k, v, dropped)
 
     def _backbone(self, params, x, positions, *, train: bool,
-                  moe_group: int = 1024):
+                  moe_group: int = 1024,
+                  moe_capacity: Optional[int] = None,
+                  keep_kv: bool = False):
+        """Final-normed hidden states and the summed aux loss; with
+        ``keep_kv`` also every layer's (k, v, dropped), stacked [L, ...]."""
         cfg = self.cfg
 
         def body(carry, lp):
             xx, aux = carry
-            xx, a = self._block(lp, xx, positions, moe_group)
-            return (xx, aux + a), None
+            xx, a, kv = self._block(lp, xx, positions, moe_group,
+                                    moe_capacity)
+            return (xx, aux + a), (kv if keep_kv else None)
 
         fn = remat_wrap(body, cfg.remat if train else "nothing")
-        (x, aux), _ = scan_or_unroll(fn, (x, jnp.zeros((), jnp.float32)),
-                                     params["layers"],
-                                     unroll=not cfg.scan_layers)
+        (x, aux), kv = scan_or_unroll(fn, (x, jnp.zeros((), jnp.float32)),
+                                      params["layers"],
+                                      unroll=not cfg.scan_layers)
         x = self.constrain(x)
-        return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+        h = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return (h, aux, kv) if keep_kv else (h, aux)
 
     def _inputs_embed(self, params, batch):
         x = self._embed(params, batch["tokens"])
@@ -362,6 +374,41 @@ class DenseLM(LMBase):
         h, _ = self._backbone(params, x, positions, train=False)
         logits = jnp.einsum("bd,dv->bv", h[:, -1], self._head_weight(params))
         return logits
+
+    def prefill_cache(self, params, tokens: jax.Array, max_len: int,
+                      capacity: Optional[int] = None):
+        """One parallel causal prefill of ``tokens`` [B, S] that also fills
+        the KV cache: ``(logits [B, V] of the last position, DecodeState,
+        dropped)``.
+
+        The state is what S ``decode_step`` calls leave behind from an
+        empty ``init_decode_state(B, max_len)``: positions 0..S-1 hold each
+        layer's post-RoPE k and v, ``kv_pos[:, :S] = arange(S)`` (the rest
+        -1) and ``pos = S``.  The prompt must fit the cache without
+        wrapping (S <= its slots).  Each sequence is its own MoE group;
+        ``capacity`` is the experts' quota in it (default: the
+        configuration's rule), and ``dropped`` counts the packets it
+        dropped over all layers.
+        """
+        B, S = tokens.shape
+        state = self.init_decode_state(B, max_len)
+        if S > state.kv_k.shape[2]:
+            raise ValueError(f"a prompt of {S} tokens does not fit a cache "
+                             f"of {state.kv_k.shape[2]} slots")
+        x = self._embed(params, tokens)
+        h, _, (k, v, dropped) = self._backbone(
+            params, x, jnp.arange(S)[None, :], train=False, moe_group=S,
+            moe_capacity=capacity, keep_kv=True)
+        logits = jnp.einsum("bd,dv->bv", h[:, -1], self._head_weight(params))
+        at = (0, 0, 0, 0, 0)
+        state = dataclasses.replace(
+            state, pos=jnp.asarray(S, jnp.int32),
+            kv_k=jax.lax.dynamic_update_slice(
+                state.kv_k, k.astype(state.kv_k.dtype), at),
+            kv_v=jax.lax.dynamic_update_slice(
+                state.kv_v, v.astype(state.kv_v.dtype), at),
+            kv_pos=state.kv_pos.at[:, :S].set(jnp.arange(S, dtype=jnp.int32)))
+        return logits, state, jnp.sum(dropped)
 
     # ---- decode -------------------------------------------------------
     def decode_state_shapes(self, shape: ShapeConfig, multi_pod: bool):

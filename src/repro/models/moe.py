@@ -53,6 +53,14 @@ def expert_capacity(group_tokens: int, moe: MoEConfig, multiple: int = 8) -> int
     return max(multiple, math.ceil(c / multiple) * multiple)
 
 
+def dropless_capacity(group_tokens: int, multiple: int = 8) -> int:
+    """The smallest capacity, in the rule's multiple, under which no packet
+    of a group of ``group_tokens`` tokens can drop: a token sends at most
+    one packet to any expert, so no expert receives more than
+    ``group_tokens``."""
+    return max(multiple, math.ceil(group_tokens / multiple) * multiple)
+
+
 def moe_apply(params, x: jax.Array, moe: MoEConfig, act: str, *,
               group_size: int = 1024,
               expert_mask: Optional[jax.Array] = None,
@@ -81,6 +89,10 @@ def moe_apply(params, x: jax.Array, moe: MoEConfig, act: str, *,
     through :func:`moe_apply_sharded` — ``registers``/``capacity`` pass
     through, ``group_size`` is ignored (the shard is the group).
 
+    ``capacity`` (every impl) overrides the per-(group, expert) quota;
+    by default it is ``expert_capacity(group, moe)``, the configuration's
+    rule.  ``dropless_capacity(group)`` makes any group dropless.
+
     Any other value names a ``repro.fabric`` backend ("reference",
     "pallas", or a registered custom): the layer then routes every group
     through a ``Fabric.transfer`` round-trip — experts are crossbar slave
@@ -97,7 +109,7 @@ def moe_apply(params, x: jax.Array, moe: MoEConfig, act: str, *,
     """
     if dispatch_impl == "gather":
         return moe_apply_gather(params, x, moe, act, group_size=group_size,
-                                expert_mask=expert_mask)
+                                expert_mask=expert_mask, capacity=capacity)
     if dispatch_impl == "sharded":
         return moe_apply_sharded(params, x, moe, act, registers=registers,
                                  axis_name=axis_name,
@@ -107,6 +119,7 @@ def moe_apply(params, x: jax.Array, moe: MoEConfig, act: str, *,
         return moe_apply_fabric(params, x, moe, act, group_size=group_size,
                                 expert_mask=expert_mask,
                                 backend=dispatch_impl,
+                                capacity=capacity,
                                 kernel_mode=kernel_mode)
     B, S, d = x.shape
     E, k = moe.n_experts, moe.top_k
@@ -126,7 +139,7 @@ def moe_apply(params, x: jax.Array, moe: MoEConfig, act: str, *,
     # --- crossbar dispatch plan: per-(group, expert) package ranks -------
     dst = top_e.reshape(G, g * k)                              # packets
     w = top_p.reshape(G, g * k).astype(x.dtype)
-    cap = expert_capacity(g, moe)
+    cap = capacity if capacity is not None else expert_capacity(g, moe)
     e_oh = jax.nn.one_hot(dst, E, dtype=jnp.int32)             # [G, gk, E]
     rank = jnp.cumsum(e_oh, axis=1) - e_oh
     rank = jnp.take_along_axis(rank, dst[..., None], axis=2,
@@ -177,7 +190,8 @@ def moe_apply(params, x: jax.Array, moe: MoEConfig, act: str, *,
 
 def moe_apply_gather(params, x: jax.Array, moe: MoEConfig, act: str, *,
                      group_size: int = 1024,
-                     expert_mask: Optional[jax.Array] = None
+                     expert_mask: Optional[jax.Array] = None,
+                     capacity: Optional[int] = None
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Gather/scatter MoE dispatch — same grant semantics, no dense
     selection tensor.
@@ -204,7 +218,7 @@ def moe_apply_gather(params, x: jax.Array, moe: MoEConfig, act: str, *,
 
     dst = top_e.reshape(G, g * k)
     w = top_p.reshape(G, g * k).astype(x.dtype)
-    cap = expert_capacity(g, moe)
+    cap = capacity if capacity is not None else expert_capacity(g, moe)
     e_oh = jax.nn.one_hot(dst, E, dtype=jnp.int32)
     rank = jnp.cumsum(e_oh, axis=1) - e_oh
     rank = jnp.take_along_axis(rank, dst[..., None], axis=2,
@@ -354,6 +368,7 @@ def moe_apply_fabric(params, x: jax.Array, moe: MoEConfig, act: str, *,
                      group_size: int = 1024,
                      expert_mask: Optional[jax.Array] = None,
                      backend: str = "reference",
+                     capacity: Optional[int] = None,
                      kernel_mode: Optional[str] = None
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """MoE dispatch as a ``repro.fabric`` transfer — one data-plane impl.
@@ -365,7 +380,8 @@ def moe_apply_fabric(params, x: jax.Array, moe: MoEConfig, act: str, *,
     ``Fabric.transfer`` with the expert FFN as ``apply_fn`` — so whichever
     backend serves the shell (reference oracle, blockwise Pallas kernels)
     also serves the MoE layer, with the paper's error codes as the drop
-    statistics.
+    statistics.  ``capacity`` is the slab depth (default: the
+    configuration's rule for the group).
     """
     from repro.core.registers import ErrorCode
 
@@ -386,7 +402,7 @@ def moe_apply_fabric(params, x: jax.Array, moe: MoEConfig, act: str, *,
 
     dst = top_e.reshape(G, g * k)
     w = top_p.reshape(G, g * k).astype(x.dtype)
-    cap = expert_capacity(g, moe)
+    cap = capacity if capacity is not None else expert_capacity(g, moe)
 
     fabric, cell = _group_fabric(E, cap, backend, kernel_mode=kernel_mode)
     canonical = cell["regs"]

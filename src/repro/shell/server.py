@@ -17,10 +17,12 @@ This server replaces the wave with an **admission queue + slot rotation**:
   live register file assigned (a region port, or the host port when the
   tenant's chain starts on-server).  Unknown apps stay queued until a
   ``Submit`` event lands — the control plane gates the data plane;
-- admission prefills are **fused**: each ``step()`` issues one batched
-  prefill call per (engine, prompt-length) group instead of replaying each
-  admitted prompt token by token, then splits the batched decode state into
-  per-slot B=1 states — identical per-slot decode semantics, one dispatch;
+- admission is **batched**: each ``step()`` issues one ``prefill_batch``
+  call per (engine, prompt-length) group, then splits the batched decode
+  state into per-slot B=1 states — identical per-slot decode semantics,
+  one dispatch.  A ``ModelEngine`` admits a group with one parallel causal
+  prefill that fills the KV cache where its model has one, and falls back
+  to a token-by-token replay through ``decode_step`` elsewhere;
 - every tick's decode traffic flows through a **shell-bound fabric**
   (``shell.fabric()``): one packet per active slot to its entry port, so
   ``port_traffic`` reads back the per-port grant counts under the *live*
@@ -31,7 +33,7 @@ Engines are pluggable: ``register_model`` builds a real jitted model engine;
 tests inject lightweight fakes via ``register_engine`` (anything with
 ``prefill(prompt) -> (tok, state)`` and ``decode(tok, state) ->
 (next_tok, state)``; an optional ``prefill_batch(prompts) -> [(tok,
-state), ...]`` opts into fused admission, and an optional
+state), ...]`` opts into batched admission, and an optional
 ``decode_batch(toks, states) -> (toks, states)`` opts into fused
 per-tick decode across slots — elementwise-identical to the loop).
 
@@ -39,8 +41,10 @@ The tick is traced with ``jax.profiler.TraceAnnotation`` host spans, which
 cost about a microsecond each while no profiler runs: ``server.tick``
 holds ``server.admit`` (one ``server.prefill`` per admission group),
 ``server.route`` and one ``server.decode`` per engine call; a
-``ModelEngine`` adds ``engine.sync`` (the device->host read of the
-tokens) and ``engine.split``.  A request's spans carry its ``rid``.
+``ModelEngine`` adds ``engine.prefill`` (its admission of a group, with
+``mode=parallel|replay``, ``B`` and ``S``), ``engine.sync`` (the
+device->host read of the tokens) and ``engine.split``.  A request's spans
+carry its ``rid``.
 """
 from __future__ import annotations
 
@@ -80,10 +84,28 @@ class StreamCompletion:
 class ModelEngine:
     """B=1 greedy-decode engine over a repro model.
 
-    Prefill is one fused, batched call: all same-length prompts admitted on
-    a tick replay through a single jitted ``lax.scan`` over ``decode_step``
-    (B = number of admissions), and the batched decode state is split into
-    per-slot B=1 states afterwards — the per-slot decode path is unchanged.
+    Admission is one batched call per group of same-length prompts, then
+    the batched decode state is split into per-slot B=1 states; the
+    per-slot decode path is unchanged.  How a group is admitted follows
+    from what the engine can observe:
+
+    - **parallel** — a model with a cache-filling prefill
+      (``DenseLM.prefill_cache``), a prompt that fits the KV cache without
+      wrapping (no sliding window shorter than it) and a family that needs
+      no extra decode inputs: one causal prefill of the ``[B, S]`` group
+      that writes the cache, reading the weights once.  Each prompt is its
+      own MoE group at a capacity that holds all of its packets
+      (``dropless_capacity(S)``), so admission drops nothing, as decode
+      does not;
+    - **replay** — everything else (SSM, hybrid and encoder-decoder
+      models, windowed prompts longer than the window): a jitted
+      ``lax.scan`` of S ``decode_step`` calls, one per prompt token.
+
+    ``_prefill_fns`` is an LRU, keyed ``(B, S)``, of the programs admission
+    runs (``fn(params, tokens[B, S])``); ``_prefill_fn(B, S)`` is always
+    the replay, cached apart.  ``admission_replays`` counts the prompts
+    admitted by replay and ``admission_dropped`` the packets parallel
+    admission dropped (0 by construction), both cumulative.
     """
 
     def __init__(self, cfg, *, max_len: int = 128, seed: int = 0):
@@ -99,35 +121,59 @@ class ModelEngine:
         self._extras = extra_decode_inputs(cfg, 1, self.model.dtype)
         self._jax = jax
         self._jnp = jnp
-        # LRU of jitted batched-replay programs, keyed by (B, S).  Bounded:
+        # LRUs of jitted admission programs, keyed by (B, S).  Bounded:
         # arbitrary user prompt lengths must not grow compiled-program
         # memory without limit on a long-running server.
         self._prefill_fns: "collections.OrderedDict[Tuple[int, int], Any]" \
             = collections.OrderedDict()
+        self._replay_fns: "collections.OrderedDict[Tuple[int, int], Any]" \
+            = collections.OrderedDict()
         self._prefill_cache_max = 16
+        # The longest prompt parallel admission takes (the KV cache's
+        # slots); 0 when the model cannot fill its cache in one call.
+        self._parallel_max_s = 0
+        if hasattr(self.model, "prefill_cache") and not self._extras:
+            self._parallel_max_s = jax.eval_shape(
+                lambda: self.model.init_decode_state(1, max_len)
+            ).kv_k.shape[2]
         # Device->host token reads (one per ``_greedy`` call), cumulative;
         # ``ServerProbe`` reports them as the ``engine_syncs`` channel.
         self.engine_syncs = 0
+        # Prompts admitted by the replay fallback (``admission_replays``
+        # channel) and packets dropped by parallel admission, cumulative.
+        self.admission_replays = 0
+        self.admission_dropped = 0
 
         def decode_one(params, state, batch_):
             return self.model.decode_step(params, state, batch_)
 
         self._decode_fn = jax.jit(decode_one)
 
-    def _greedy(self, logits):
+    def _greedy(self, logits, dropped=None):
+        """The greedy tokens of ``logits`` (and ``dropped``, read with them
+        in the same device->host transfer)."""
         from repro.runtime.serve import greedy_tokens
         self.engine_syncs += 1
         with TraceAnnotation("engine.sync", B=logits.shape[0]):
-            return [int(t) for t in np.asarray(greedy_tokens(logits,
-                                                             self.cfg.vocab))]
+            toks = greedy_tokens(logits, self.cfg.vocab)
+            if dropped is not None:
+                toks, dropped = self._jax.device_get((toks, dropped))
+            return [int(t) for t in np.asarray(toks)], dropped
+
+    def _cached(self, cache, key, build):
+        fn = cache.get(key)
+        if fn is not None:
+            cache.move_to_end(key)
+            return fn
+        fn = cache[key] = build()
+        if len(cache) > self._prefill_cache_max:
+            cache.popitem(last=False)
+        return fn
 
     def _prefill_fn(self, B: int, S: int):
-        """One jitted (scan-fused) batched replay per (B, S) shape."""
-        key = (B, S)
-        fn = self._prefill_fns.get(key)
-        if fn is not None:
-            self._prefill_fns.move_to_end(key)
-        else:
+        """The token-by-token replay of a [B, S] group: one jitted
+        ``lax.scan`` of ``decode_step`` per (B, S) shape."""
+        def build():
             jax, jnp = self._jax, self._jnp
             from repro.runtime.serve import extra_decode_inputs
             extras = extra_decode_inputs(self.cfg, B, self.model.dtype)
@@ -144,10 +190,28 @@ class ModelEngine:
                                                  jnp.swapaxes(tokens, 0, 1))
                 return logits_seq[-1], state
 
-            fn = self._prefill_fns[key] = jax.jit(replay)
-            if len(self._prefill_fns) > self._prefill_cache_max:
-                self._prefill_fns.popitem(last=False)
-        return fn
+            return jax.jit(replay)
+
+        return self._cached(self._replay_fns, (B, S), build)
+
+    def _admission_fn(self, B: int, S: int):
+        """The program admission runs for a [B, S] group, and whether it
+        is the parallel prefill."""
+        parallel = S <= self._parallel_max_s
+        if not parallel:
+            return self._cached(self._prefill_fns, (B, S),
+                                lambda: self._prefill_fn(B, S)), False
+        from repro.models.moe import dropless_capacity
+        max_len, capacity = self.max_len, dropless_capacity(S)
+
+        def build():
+            def admit(params, tokens):                      # tokens [B, S]
+                return self.model.prefill_cache(params, tokens, max_len,
+                                                capacity)
+
+            return self._jax.jit(admit)
+
+        return self._cached(self._prefill_fns, (B, S), build), True
 
     def _split_state(self, state, B: int):
         """Slice a B-batched decode state into B single-request states.
@@ -177,15 +241,26 @@ class ModelEngine:
             return [slice_i(i) for i in range(B)]
 
     def prefill_batch(self, prompts) -> List[Tuple[int, Any]]:
-        """Fused admission prefill for same-length prompts (one call)."""
+        """Admission of same-length prompts in one call: parallel where
+        the model allows it, else the replay."""
         B = len(prompts)
         S = len(prompts[0])
         assert all(len(p) == S for p in prompts), \
             "prefill_batch groups same-length prompts"
         tokens = np.stack([np.asarray(p, np.int32) for p in prompts])
-        logits, state = self._prefill_fn(B, S)(self.params, tokens)
-        toks = self._greedy(logits)
-        return list(zip(toks, self._split_state(state, B)))
+        fn, parallel = self._admission_fn(B, S)
+        with TraceAnnotation("engine.prefill",
+                             mode="parallel" if parallel else "replay",
+                             B=B, S=S):
+            if parallel:
+                logits, state, dropped = fn(self.params, tokens)
+                toks, dropped = self._greedy(logits, dropped)
+                self.admission_dropped += int(dropped)
+            else:
+                logits, state = fn(self.params, tokens)
+                toks, _ = self._greedy(logits)
+                self.admission_replays += B
+            return list(zip(toks, self._split_state(state, B)))
 
     def prefill(self, prompt: np.ndarray) -> Tuple[int, Any]:
         """Single-prompt prefill (the B=1 case of ``prefill_batch``)."""
@@ -196,7 +271,8 @@ class ModelEngine:
         batch = {"tokens": jnp.asarray([[tok]], dtype=jnp.int32),
                  **self._extras}
         logits, state = self._decode_fn(self.params, state, batch)
-        return self._greedy(logits)[0], state
+        toks, _ = self._greedy(logits)
+        return toks[0], state
 
 
 @dataclasses.dataclass(slots=True)
@@ -295,6 +371,13 @@ class ElasticServer:
         return sum(getattr(e, "engine_syncs", 0)
                    for e in self._engines.values())
 
+    @property
+    def admission_replays(self) -> int:
+        """Prompts the registered engines admitted by token-by-token
+        replay (``ModelEngine.admission_replays``)."""
+        return sum(getattr(e, "admission_replays", 0)
+                   for e in self._engines.values())
+
     # ---- engines ------------------------------------------------------
     def register_model(self, app_id: int, cfg, *, max_len: int = 128,
                        seed: int = 0) -> None:
@@ -311,7 +394,7 @@ class ElasticServer:
     def register_engine(self, app_id: int, engine: Any) -> None:
         """Duck-typed engine injection: anything with ``prefill(prompt) ->
         (tok, state)`` and ``decode(tok, state) -> (tok, state)`` (an
-        optional ``prefill_batch`` opts into fused admission; an optional
+        optional ``prefill_batch`` opts into batched admission; an optional
         ``decode_batch(toks, states)`` fuses each tick's decode pass).
 
         >>> import numpy as np
@@ -410,9 +493,9 @@ class ElasticServer:
     def _admit(self) -> int:
         """Fill free slots from the queue; shell-gated. Returns admissions.
 
-        Prefills are fused: one ``prefill_batch`` per (engine,
+        Prefills are batched: one ``prefill_batch`` per (engine,
         prompt-length) group of this tick's admissions, instead of one
-        replay per request (engines without ``prefill_batch`` fall back to
+        call per request (engines without ``prefill_batch`` fall back to
         per-request ``prefill``)."""
         if not self.queue:
             return 0                # steady state: skip the free-slot scan
