@@ -83,7 +83,7 @@ class Prefill:
                             make_mesh((1, 1), ("data", "model")),
                             multi_pod=False)
         expect = jax.tree.map(lambda s: tuple(s.shape), bundle.arg_structs[0])
-        if expect != weights.shapes(model):
+        if expect != weights.shapes(cell.arch.layout(model)):
             raise ValueError("the benchmark's weight layout no longer matches "
                              "the program's parameter tree")
         self.step = jax.jit(bundle.step, in_shardings=bundle.in_shardings,
@@ -94,11 +94,9 @@ class Prefill:
 
     def reseed(self, seed: int) -> None:
         """Weights and traffic of ``seed`` for the same compiled step."""
-        from bench import weights
-
         self.seed = seed
         self.tokens = tokens_fn(self.cell.traffic, self.vocab, seed)
-        self.params = weights.generate(self.cell.model, seed)
+        self.params = self.cell.weights(seed)
         self.outs = []
 
     def _call(self, i: int):
@@ -183,12 +181,11 @@ def check(cell, seed: int, tokens, served, reference=None, control=None,
     reference's router logits."""
     import jax
 
-    from bench import compare, weights
-    from bench.reference import Reference
+    from bench import compare
 
-    model = cell.model
-    ref = reference or Reference(model, cell.config.get("routing"))
-    params = weights.generate(model, seed)
+    ref = reference or cell.arch.Reference(cell.model,
+                                           cell.config.get("routing"))
+    params = cell.weights(seed)
     want, margins = ref.last_logits(params, tokens, router)
     if control is not None:
         served, _ = control.last_logits(params, tokens)
@@ -205,11 +202,10 @@ def calibrate(cell, seeds, control_seeds, seconds: float, probe=None):
     installed, also how far the program's router logits of the sample lie
     from the reference's."""
     from bench import compare
-    from bench.reference import Reference
 
     routing = cell.config.get("routing")
-    ref = Reference(cell.model, routing)
-    control = Reference(cell.model, routing, "fp8")
+    ref = cell.arch.Reference(cell.model, routing)
+    control = cell.arch.Reference(cell.model, routing, "fp8")
     n = cell.check["check_sequences"]
     p = None
     for seed in seeds:
@@ -238,8 +234,6 @@ def calibrate(cell, seeds, control_seeds, seconds: float, probe=None):
 def run(cell, seed: int, seconds: float, *, trace: bool, clock,
         step_wrapper=None, trace_dir=None) -> dict:
     """One run: set-up, the window (traced or not), then the check."""
-    from bench import counts
-
     t_import = clock.setup_done()
     p = Prefill(cell, seed, step_wrapper)
     t_build = clock.setup_done()
@@ -260,10 +254,10 @@ def run(cell, seed: int, seconds: float, *, trace: bool, clock,
     seqs = steps * p.B
     out.update(steps=steps, window_s=secs, attempted=seqs, hlo=hlo,
                e2e={"prefill_tok_s": seqs * p.S / secs},
-               flops_per_step=counts.prefill_flops(cell.model, p.B, p.S))
+               flops_per_step=cell.arch.prefill_flops(cell.model, p.B, p.S))
     if cell.model.get("moe"):
         out["fabric_scatter"] = True     # the dispatch's is the only scatter
-        out["fabric_bytes_per_step"] = counts.fabric_bytes(
+        out["fabric_bytes_per_step"] = cell.arch.fabric_bytes(
             cell.model, p.B * p.S, cell.config["routing"]["group_tokens"])
     out["memory_peak_bytes"] = clock.memory_peak()
     tokens, served = p.sample(cell.check["check_sequences"])

@@ -110,7 +110,8 @@ class Served:
         mix, model = cell.traffic, cell.model
         srv = mix["server"]
         self.vocab, self.max_len = model["vocab"], srv["max_len"]
-        layer_bytes = 2 * weights.n_params(model) // model["n_layers"]
+        layer_bytes = (2 * weights.n_params(cell.arch.layout(model))
+                       // model["n_layers"])
         fp = ModuleFootprint(param_bytes=layer_bytes, flops_per_token=0.0,
                              activation_bytes_per_token=2 * model["d_model"])
         shell = Shell([Region(rid=i, n_chips=1, hbm_bytes=16 * GB)
@@ -123,7 +124,7 @@ class Served:
                                    max_len=self.max_len)
         self.engine = self.server.engine(APP)
         got = jax.tree.map(lambda x: tuple(x.shape), self.engine.params)
-        if got != weights.shapes(model):
+        if got != weights.shapes(cell.arch.layout(model)):
             raise ValueError("the benchmark's weight layout no longer matches "
                              "the engine's parameter tree")
         for leaf in jax.tree.leaves(self.engine.params):
@@ -152,10 +153,8 @@ class Served:
     def reseed(self, seed: int) -> None:
         """Weights and requests of ``seed`` for the same server and
         compiled programs."""
-        from bench import weights
-
         self.seed = seed
-        self.engine.params = weights.generate(self.cell.model, seed)
+        self.engine.params = self.cell.weights(seed)
         self.requests = closed_requests(self.cell.traffic, self.vocab, seed)
 
     def warm(self) -> None:
@@ -175,7 +174,6 @@ class Served:
         """The closed loop until ``seconds`` or ``max_ticks``: ticks,
         seconds, tokens delivered and the FLOPs they require, the finished
         requests {rid: (client, prompt, tokens)} and the token gaps."""
-        from bench import counts
         from repro.shell.server import StreamRequest
 
         server, clients = self.server, self.cell.traffic["clients"]
@@ -205,8 +203,8 @@ class Served:
             got += [(c.rid, len(c.tokens)) for c in finished]
             delivered += len(got)
             for rid, n in got:
-                flops += counts.token_flops(self.cell.model,
-                                            len(prompts[rid]) + n - 1)
+                flops += self.cell.arch.token_flops(
+                    self.cell.model, len(prompts[rid]) + n - 1)
                 if rid in last:
                     gaps.append(now - last[rid])
                 last[rid] = now
@@ -308,14 +306,12 @@ def check(cell, seed: int, picked: list, reference=None, control=None,
     import jax
     import jax.numpy as jnp
 
-    from bench import compare, weights
-    from bench.reference import Reference
+    from bench import compare
 
-    model = cell.model
-    ref = reference or Reference(model, None)
+    ref = reference or cell.arch.Reference(cell.model, None)
     rows, pos, want = teacher_forced(picked, cell.traffic["server"]["max_len"],
                                      cell.check["check_requests"])
-    params = weights.generate(model, seed)
+    params = cell.weights(seed)
     logits, margins = ref.all_logits(params, rows, router)
     if control is not None:
         firsts, _ = control.all_logits(params, rows)
@@ -358,10 +354,9 @@ def calibrate(cell, seeds, control_seeds, seconds: float, probe=None):
     ``probe`` installed, also how far the program's router logits of the
     sample lie from the reference's."""
     from bench import compare
-    from bench.reference import Reference
 
-    ref = Reference(cell.model, None)
-    control = Reference(cell.model, None, "fp8")
+    ref = cell.arch.Reference(cell.model, None)
+    control = cell.arch.Reference(cell.model, None, "fp8")
     s = None
     for seed in seeds:
         if s is None:

@@ -1,6 +1,7 @@
 """decode_mfu: the FLOPs the window's served tokens require (bench's own
-count, ``bench.counts.token_flops`` at each token's position) over the
-window's host-clock seconds and the chips' published peak, in percent."""
+count, the architecture's ``token_flops`` at each token's position) over
+the window's host-clock seconds and the chips' published peak, in
+percent."""
 
 
 def read(ctx):

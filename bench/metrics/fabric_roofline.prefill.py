@@ -1,7 +1,7 @@
 """fabric_roofline.prefill: the least time the chip needs to move the
-bytes any crossbar must move for the window's steps
-(``bench.counts.fabric_bytes`` at the peak HBM bandwidth) over the device
-time of the fabric's ops (as ``fabric_share`` finds them), in percent."""
+bytes any crossbar must move for the window's steps (the architecture's
+``fabric_bytes`` at the peak HBM bandwidth) over the device time of the
+fabric's ops (as ``fabric_share`` finds them), in percent."""
 from bench.trace import FABRIC_PATHS, fabric_s
 
 PATHS = FABRIC_PATHS

@@ -1,5 +1,5 @@
 """prefill_mfu: the FLOPs the window's prefill steps require (bench's own
-count, ``bench.counts.prefill_flops``) over the window's host-clock
+count, the architecture's ``prefill_flops``) over the window's host-clock
 seconds and the chips' published peak, in percent."""
 
 

@@ -2,13 +2,13 @@
 whose timed path is broken underneath.  Small widths on the CPU; the full
 widths run on the chip (``bench/calibrate.py``)."""
 import copy
+import hashlib
 
 import numpy as np
 import pytest
 
-from bench import compare, spec, weights
-from bench.drivers.prefill import Prefill, check
-from bench.reference import Reference
+from bench import compare, spec
+from bench.drivers.prefill import Prefill, check, tokens_fn
 from bench.run import run_cell
 
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
@@ -44,15 +44,68 @@ def test_reference_equals_the_program_in_float32(name, capacity_factor):
     p = Prefill(cell, 3)
     served = np.asarray(p.step(p.params, {"tokens": p.tokens(0)}))
     toks = np.asarray(p.tokens(0))
-    ref, _ = Reference(cell.model, cell.config.get("routing")).last_logits(
-        weights.generate(cell.model, 3), toks)
+    ref, _ = cell.arch.Reference(
+        cell.model, cell.config.get("routing")).last_logits(cell.weights(3),
+                                                            toks)
     assert compare.logit_errors(served[:, :512], ref).max() < 1e-5
     if capacity_factor == 0.5:
         m = copy.deepcopy(cell.model)
         m["moe"]["capacity_factor"] = 1.25
-        loose, _ = Reference(m, cell.config["routing"]).last_logits(
-            weights.generate(m, 3), toks)
+        loose, _ = cell.arch.Reference(m, cell.config["routing"]).last_logits(
+            cell.weights(3), toks)
         assert compare.logit_errors(served[:, :512], loose).min() > 0.1
+
+
+def digest(*arrays) -> str:
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.asarray(a)
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+# sha256 of the weights, the first step's tokens, and the reference's
+# last-position logits and router margins at the SMALL sizes for seed 3,
+# as drawn before the architecture moved to ``bench/arch/decoder.py``.
+PINNED = {
+    MIXTRAL: {
+        "weights": "28b18ba4d69590b1061dbc3b15881cf8"
+                   "f5c006d6c70ccce8ad98e28887c03109",
+        "tokens": "ca0dda78d08d86636ded44b0f57be5e1"
+                  "e2bb8388a071b05839fd80211f159cfc",
+        "logits": "7e6aa9e6dad81849a12241fb11565b74"
+                  "19638bb254703dd5667a49323b330999",
+        "margin": "df3c86ec6fe24ddeddeb8a5b9071e725"
+                  "93a24e1404e8b7c8231c166baec2fea0"},
+    QWEN: {
+        "weights": "5c9f14ad5c6a6c16543d0185856174b1"
+                   "f795b2206e8ce2a35c7ce799b4b10e3a",
+        "tokens": "ca0dda78d08d86636ded44b0f57be5e1"
+                  "e2bb8388a071b05839fd80211f159cfc",
+        "logits": "c26a579e11d7886e75fc9bd67b00ea37"
+                  "67236a51815d2719a2b466d6c94608d0",
+        "margin": "61bb8569fd84fac54f476f70b85a29f9"
+                  "3f1f80ab24ed40bf8f89cc4e4abea42a"},
+}
+
+
+@pytest.mark.parametrize("name", [MIXTRAL, QWEN])
+def test_weights_traffic_and_reference_logits_are_pinned(name):
+    """The same seed draws bit-identical weights and tokens, and the
+    reference gives bit-identical logits, through the cell's
+    architecture."""
+    import jax
+
+    cell = small(name)
+    params = cell.weights(3)
+    toks = tokens_fn(cell.traffic, cell.model["vocab"], 3)(0)
+    logits, margin = cell.arch.Reference(
+        cell.model, cell.config.get("routing")).last_logits(
+            params, np.asarray(toks))
+    assert {"weights": digest(*jax.tree.leaves(params)),
+            "tokens": digest(toks), "logits": digest(logits),
+            "margin": digest(margin)} == PINNED[name]
 
 
 @pytest.mark.parametrize("name", [MIXTRAL, QWEN])
@@ -69,7 +122,7 @@ def test_fp8_control_fails_and_the_program_passes(name):
     toks, served = p.sample(4)
     p.free()
     program = check(cell, 11, toks, served)
-    control = check(cell, 11, toks, served, control=Reference(
+    control = check(cell, 11, toks, served, control=cell.arch.Reference(
         cell.model, cell.config.get("routing"), "fp8"))
     assert program["compared"] >= 1
     assert program["logit_err"] < limit < control["logit_err"]

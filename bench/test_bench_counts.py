@@ -1,17 +1,26 @@
-"""FLOP and byte counts of the yardstick against hand counts."""
+"""FLOP and byte counts of the yardstick against hand counts, through
+each configuration's architecture."""
 import json
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-from bench import counts
+from bench import counts, spec
 
 CONFIGS = Path(__file__).parent / "configs"
 
 
+def config(name):
+    return json.loads((CONFIGS / f"{name}.json").read_text())
+
+
 def model(name):
-    return json.loads((CONFIGS / f"{name}.json").read_text())["model"]
+    return config(name)["model"]
+
+
+def arch(name):
+    return spec.architecture(config(name)["arch"])
 
 
 def test_mixtral_prefill_flops_hand_count():
@@ -22,7 +31,8 @@ def test_mixtral_prefill_flops_hand_count():
     head = 2 * B * 4096 * 32000
     want = 2 * (proj + attn + ffn) + head
     assert want == 13_196_396_068_864
-    assert counts.prefill_flops(model("mixtral_8x7b_2l"), B, S) == want
+    assert arch("mixtral_8x7b_2l").prefill_flops(
+        model("mixtral_8x7b_2l"), B, S) == want
 
 
 def test_qwen_prefill_flops_hand_count():
@@ -33,7 +43,8 @@ def test_qwen_prefill_flops_hand_count():
     head = 2 * B * 2048 * 151936
     want = 36 * (proj + attn + mlp) + head
     assert want == 47_935_532_302_336
-    assert counts.prefill_flops(model("qwen2_5_3b"), B, S) == want
+    assert arch("qwen2_5_3b").prefill_flops(model("qwen2_5_3b"), B, S) \
+        == want
 
 
 def test_window_limits_attended_pairs():
@@ -79,7 +90,8 @@ def test_fabric_bytes_equal_the_least_movement_without_drops(seed):
         b, kept = _moved_bytes(dst, cap, 8, d)
         total, kept_all = total + b, kept_all + kept
     assert kept_all == 8 * g * 2           # uniform routing drops nothing
-    assert counts.fabric_bytes(m, 8 * g, g) == m["n_layers"] * total
+    assert arch("mixtral_8x7b_2l").fabric_bytes(m, 8 * g, g) \
+        == m["n_layers"] * total
 
 
 def test_fabric_bytes_never_exceed_the_programs_data_plane():
@@ -91,7 +103,7 @@ def test_fabric_bytes_never_exceed_the_programs_data_plane():
     offered = T * 2
     program = m["n_layers"] * (2 * T * d * 2 + 2 * offered * d * 2
                                + 16 * offered)
-    assert counts.fabric_bytes(m, T, g) <= program
+    assert arch("mixtral_8x7b_2l").fabric_bytes(m, T, g) <= program
 
 
 @pytest.mark.parametrize("position", [0, 127, 254])
@@ -104,14 +116,15 @@ def test_token_flops_hand_count(position):
     attn = 4 * 32 * 128 * keys
     ffn = 2 * 4096 * 8 + 2 * 6 * 4096 * 14336
     want = 2 * (proj + attn + ffn) + 2 * 4096 * 32000
-    assert counts.token_flops(model("mixtral_8x7b_2l"), position) == want
+    assert arch("mixtral_8x7b_2l").token_flops(
+        model("mixtral_8x7b_2l"), position) == want
 
 
 def test_token_flops_of_the_last_position_are_one_prefill_token():
     """A prefill of one sequence requires what its tokens require one by
     one, less the head at every position but the last."""
-    m = model("qwen2_5_3b")
+    m, a = model("qwen2_5_3b"), arch("qwen2_5_3b")
     S = 64
     head = 2 * 2048 * 151936
-    by_token = sum(counts.token_flops(m, p) for p in range(S))
-    assert counts.prefill_flops(m, 1, S) == by_token - (S - 1) * head
+    by_token = sum(a.token_flops(m, p) for p in range(S))
+    assert a.prefill_flops(m, 1, S) == by_token - (S - 1) * head

@@ -90,6 +90,82 @@ def test_config_file_states_what_is_run(conf):
     if not f.get("use_sliding_window", f["sliding_window"] is not None):
         assert m["attn_window"] is None
     assert (f["model_type"] == "qwen2") == m["qkv_bias"]
+    assert (spec.ARCH_DIR / f"{f['arch']}.py").is_file()
+    assert f["arch"] in spec.architectures()
+
+
+def test_architectures_are_found_by_name(monkeypatch, tmp_path):
+    """A new architecture is a new file ``bench/arch/<arch>.py`` that a
+    configuration names: the cell draws its layout."""
+    import numpy as np
+
+    (tmp_path / "toy.py").write_text(
+        "def layout(model):\n"
+        "    return {'w': ((2, model['d_model']), 'normal', 1.0),\n"
+        "            'g': ((model['d_model'],), 'gain', 0.0)}\n")
+    conf = dict(json.loads((ROOT / "bench/configs/qwen2_5_3b.json")
+                           .read_text()), arch="toy")
+    (tmp_path / "toy.json").write_text(json.dumps(conf))
+    bench = dict(BENCH, configs=[dict(c, file=str(tmp_path / "toy.json"))
+                                 for c in BENCH["configs"]])
+    monkeypatch.setattr(spec, "ARCH_DIR", tmp_path)
+    assert spec.architectures() == ["toy"]
+    cell = spec.load_cell("qwen2_5_3b.prefill_8k", bench)
+    assert cell.arch is spec.architecture("toy")
+    params = cell.weights(3)
+    assert params["w"].shape == (2, 2048) and params["w"].dtype == "bfloat16"
+    assert (np.asarray(params["g"], np.float32) == 1.0).all()
+
+
+def test_an_unknown_or_missing_architecture_is_refused(tmp_path):
+    conf = json.loads((ROOT / "bench/configs/qwen2_5_3b.json").read_text())
+    for name, arch in [("unknown", "no_such_arch"), ("init", "__init__"),
+                       ("path", "../spec")]:
+        (tmp_path / f"{name}.json").write_text(json.dumps(dict(conf,
+                                                               arch=arch)))
+    del conf["arch"]
+    (tmp_path / "missing.json").write_text(json.dumps(conf))
+    for name in ("unknown", "init", "path", "missing"):
+        bench = dict(BENCH, configs=[dict(c, file=str(tmp_path /
+                                                      f"{name}.json"))
+                                     for c in BENCH["configs"]])
+        with pytest.raises(ValueError, match="known: .*'decoder'"):
+            spec.load_cell("qwen2_5_3b.prefill_8k", bench)
+
+
+def test_model_config_builds_every_block():
+    """Each nested block of the model becomes its field's dataclass, and
+    each JSON list a tuple, with no edit for a new block."""
+    import dataclasses
+    from typing import Optional, Tuple
+
+    from repro.models.config import HybridConfig, MoEConfig
+
+    cfg = spec.model_config({
+        "name": "hybrid", "family": "hybrid", "n_layers": 3,
+        "d_model": 64, "n_heads": 4, "n_kv_heads": 2, "d_ff": 128,
+        "vocab": 512, "moe": None,
+        "hybrid": {"pattern_rec": 2, "lru_width": 96, "attn_window": 32}})
+    cfg.validate()
+    assert cfg.hybrid == HybridConfig(pattern_rec=2, lru_width=96,
+                                      attn_window=32)
+    assert cfg.moe is None
+    assert spec.model_config(dict(
+        json.loads((ROOT / "bench/configs/mixtral_8x7b_2l.json")
+                   .read_text())["model"])).moe == MoEConfig(
+        n_experts=8, top_k=2, capacity_factor=1.25, dispatch="pallas")
+
+    @dataclasses.dataclass(frozen=True)
+    class Outer:
+        blocks: Tuple[HybridConfig, ...]
+        sizes: Optional[Tuple[int, ...]] = None
+
+    out = spec.build(Outer, {"blocks": [{"pattern_rec": 1}, {}],
+                             "sizes": [1, 2]})
+    assert out == Outer(blocks=(HybridConfig(pattern_rec=1),
+                                HybridConfig()), sizes=(1, 2))
+    with pytest.raises(ValueError, match="not a dataclass"):
+        spec.build(Outer, {"blocks": [], "sizes": {"a": 1}})
 
 
 def test_readers_stay_silent_without_data():

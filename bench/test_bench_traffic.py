@@ -2,7 +2,7 @@
 import numpy as np
 import pytest
 
-from bench import traffic, weights
+from bench import spec, traffic, weights
 from bench.drivers import prefill, serve_closed
 
 MIX = {"kind": "prefill", "batch": 2, "seq_len": 16, "tokens": "uniform"}
@@ -39,9 +39,10 @@ def test_high_bits_of_the_seed_count():
 def test_weights_repeat_for_a_seed_and_differ_across_seeds():
     import jax
 
-    a = jax.tree.leaves(weights.generate(TINY, 5))
-    b = jax.tree.leaves(weights.generate(TINY, 5))
-    c = jax.tree.leaves(weights.generate(TINY, 6))
+    layout = spec.architecture("decoder").layout(TINY)
+    a = jax.tree.leaves(weights.generate(layout, 5, "bfloat16"))
+    b = jax.tree.leaves(weights.generate(layout, 5, "bfloat16"))
+    c = jax.tree.leaves(weights.generate(layout, 6, "bfloat16"))
     assert all(np.array_equal(np.asarray(x, np.float32),
                               np.asarray(y, np.float32)) for x, y in zip(a, b))
     assert not any(np.array_equal(np.asarray(x, np.float32),
@@ -59,7 +60,7 @@ def test_weight_layout_matches_the_program():
                  moe=moe, tied_embeddings=moe is None)
         want = jax.tree.map(lambda s: tuple(s.shape),
                             build_model(model_config(m)).param_shapes())
-        assert want == weights.shapes(m)
+        assert want == weights.shapes(spec.architecture("decoder").layout(m))
 
 
 @pytest.mark.parametrize("bad", [{"batch": 2}, {"kind": "prefill"},
